@@ -18,6 +18,9 @@ import time
 
 
 def main() -> None:
+    from repro.core.compile_cache import setup_compile_cache
+
+    setup_compile_cache()
     from benchmarks import (
         ablation, cold_vs_warm, continuous, core_sensitivity, dynamic_load,
         e2e_speedup, io_formats, kernel_table, plan_generation,

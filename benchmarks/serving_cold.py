@@ -210,6 +210,9 @@ def main(argv=None):
     ap.add_argument("--smoke", action="store_true",
                     help="small sizes + hard-fail gates (CI)")
     args = ap.parse_args(argv)
+    from repro.core.compile_cache import setup_compile_cache
+
+    setup_compile_cache()
     failures: list = []
     run_concurrent(failures)
     run_cold_llm(failures)

@@ -4,7 +4,7 @@ Arms:
   * failover — two supervised worker processes; a cold-start request is
     dispatched and its worker is SIGKILLed mid-flight. Gates: the request
     fails over to the sibling and completes within its deadline, the
-    output is bit-identical to an isolated single-server cold start, the
+    output is bit-identical to an isolated one-worker cold start, the
     victim restarts under the exponential-backoff policy and serves
     again, and nothing leaks (no stuck in-flight entries, queues empty).
   * priority — worker slots saturated with batch-lane requests; an
@@ -38,7 +38,6 @@ try:
 except ImportError:  # invoked as `python benchmarks/serving_frontdoor.py`
     sys.path.insert(0, str(Path(__file__).resolve().parent.parent))
 from repro.executor.frontdoor import BATCH, INTERACTIVE, FrontDoor
-from repro.executor.server import ColdServer
 from repro.faults import DeadlineExceeded
 from repro.models.cnn import build_cnn
 
@@ -51,14 +50,21 @@ def _gate(ok: bool, msg: str, failures: list):
         failures.append(msg)
 
 
+def isolated_output(root, x, **model_kw) -> np.ndarray:
+    """One worker's isolated cold start, served by a one-worker front door:
+    the model runs in a worker, never in this process (on a TPU host the
+    parent must leave every chip to the workers). A later front door on the
+    same ``root`` shares this one's profile DB, hence its plan."""
+    with FrontDoor(root, n_workers=1, worker_args=WORKER_ARGS) as fd:
+        fd.add_model("mnet", "repro.models.cnn:build_cnn", **model_kw)
+        return np.asarray(fd.request("mnet", x).result(120)["output"])
+
+
 def run_failover(failures: list, *, image=32, width=0.5):
     root = tempfile.mkdtemp(prefix="nnv12_frontdoor_")
-    layers, x = build_cnn("mobilenet", image=image, width=width)
-
-    iso = ColdServer(root + "/iso", n_little=2)
-    iso.add_model("mnet", layers)
-    iso.decide("mnet", x, n_little=2)
-    ref = np.asarray(iso.cold_start("mnet", x).result().output)
+    _, x = build_cnn("mobilenet", image=image, width=width)
+    ref = isolated_output(root + "/fd", x, name="mobilenet", image=image,
+                          width=width)
 
     fd = FrontDoor(root + "/fd", n_workers=2, worker_args=WORKER_ARGS)
     fd.start()
@@ -286,6 +292,9 @@ def main(argv=None):
     ap.add_argument("--smoke", action="store_true",
                     help="small sizes + hard-fail gates (CI)")
     args = ap.parse_args(argv)
+    from repro.core.compile_cache import setup_compile_cache
+
+    setup_compile_cache()
     failures: list = []
     run_failover(failures, **({"image": 24, "width": 0.4}
                               if args.smoke else {}))

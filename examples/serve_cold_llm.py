@@ -13,6 +13,7 @@ import jax
 import numpy as np
 
 from repro.configs import get_config
+from repro.core.compile_cache import setup_compile_cache
 from repro.core.llm_graph import build_llm_graph
 from repro.executor.llm_bridge import cold_start_llm
 from repro.executor.server import ColdServer
@@ -20,6 +21,7 @@ from repro.models import transformer as T
 
 
 def main():
+    setup_compile_cache()
     # ~65M-param smollm-family model (f32 master checkpoint ≈ 260 MB on disk)
     cfg = get_config("smollm-360m").reduced(
         num_layers=8, d_model=512, d_ff=1536, num_heads=8, num_kv_heads=4,

@@ -93,6 +93,7 @@ import json
 import mmap as mmap_mod
 import os
 import struct
+import threading
 from pathlib import Path
 from typing import Callable, Dict, List, Optional, Sequence, Set, Tuple
 
@@ -523,6 +524,11 @@ class SuperBundle:
         # mmap stays the sequential-baseline/profiler path, the engine
         # reads the same extents at queue depth through this descriptor
         self._fd: Optional[int] = os.open(self.path, os.O_RDONLY)
+        # reads the engine still owes on _fd: close() leaves the descriptor
+        # to the last of them, so no read lands on a closed (or reused) fd
+        self._fd_lock = threading.Lock()
+        self._fd_reads = 0
+        self._closed = False
         self.last_readahead: Optional[dict] = None
         self.header, self.version, self._hlen = _parse_super_header(
             self._buf, src=self.path)
@@ -544,9 +550,19 @@ class SuperBundle:
             self._mm.close()
         except BufferError:
             pass  # live views pin the map; the GC reclaims it with them
-        if self._fd is not None:
+        with self._fd_lock:
+            self._closed = True
+            self._close_fd_if_idle()
+
+    def _close_fd_if_idle(self):
+        if self._closed and not self._fd_reads and self._fd is not None:
             os.close(self._fd)
             self._fd = None
+
+    def _read_done(self):
+        with self._fd_lock:
+            self._fd_reads -= 1
+            self._close_fd_if_idle()
 
     def __enter__(self):
         return self
@@ -674,7 +690,7 @@ class SuperBundle:
         surface in ``self.dropped``, raw mismatches raise
         ``IntegrityError`` — except checksums audit the engine-read bytes
         themselves, so the audit covers the path actually served."""
-        if self._fd is None:
+        if self._closed:
             raise RuntimeError(f"{self.path}: submit_read on closed bundle")
         sect = self._layers.get(layer)
         if not sect:
@@ -794,10 +810,22 @@ class PendingLayerRead:
             tickets = []
             try:
                 for e in self._entries:
-                    tickets.append((e, self.engine.submit(
-                        self.sb._fd, e["offset"], e["nbytes"],
-                        key=f"{self.layer}/{e['name']}",
-                        injector=self.injector)))
+                    with self.sb._fd_lock:
+                        if self.sb._closed:
+                            raise RuntimeError(
+                                f"{self.sb.path}: submit_read on closed "
+                                "bundle")
+                        self.sb._fd_reads += 1
+                    try:
+                        t = self.engine.submit(
+                            self.sb._fd, e["offset"], e["nbytes"],
+                            key=f"{self.layer}/{e['name']}",
+                            injector=self.injector,
+                            on_done=self.sb._read_done)
+                    except BaseException:
+                        self.sb._read_done()
+                        raise
+                    tickets.append((e, t))
             except BaseException:
                 for _, t in tickets:
                     t.abandon()

@@ -34,7 +34,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.checkpoint import LayerStore, atomic_write_text
-from repro.core.compile_cache import CompileCache
+from repro.core.compile_cache import CompileCache, executable_cache_dir
 from repro.core.pipeline import PipelineJob, PipelineRuntime, RunResult
 from repro.executor.pool import CorePool
 from repro.core.profiler import CoreModel, OpProfile, ProfileDB, Profiler
@@ -91,7 +91,7 @@ class ColdEngine:
         self.kernel_allowlist = (set(kernel_allowlist)
                                  if kernel_allowlist is not None else None)
         self.compile_cache = CompileCache(
-            Path(store_dir) / "xla_cache" if shader_cache else None)
+            executable_cache_dir() if shader_cache else None)
         # shape-class sharing: profile/compile one representative per class
         # and fan out. False = the legacy per-layer path (every layer keyed
         # uniquely) — kept for baselines and equivalence tests.

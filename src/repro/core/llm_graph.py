@@ -16,6 +16,7 @@ engine's dependency model); residual adds live inside each block unit.
 """
 from __future__ import annotations
 
+import dataclasses
 from typing import Dict, List, Tuple
 
 import jax
@@ -211,51 +212,85 @@ LOSSY_KERNELS.setdefault("tblock", [TBlockInt8(), TBlockInt4()])
 LOSSY_KERNELS.setdefault("lmhead", [HeadInt8(), HeadInt4()])
 
 
+_OP_TYPES = {"embed": "embed", "lm_head": "lmhead"}
+
+
+def _layer_names(cfg: ArchConfig) -> List[str]:
+    return (["embed"] + [f"block{i:03d}" for i in range(cfg.num_layers)]
+            + ["lm_head"])
+
+
+def _layer_weights(cfg: ArchConfig, params) -> Dict[str, Dict[str, jax.Array]]:
+    """{layer: {weight: array}} of the cold graph, cut from T-format params
+    (traceable, so ``jax.eval_shape`` gives the shapes at no cost)."""
+    blocks = params["blocks"]
+    out = {"embed": {"embed": params["embed"]}}
+    keys = ([("ln1",), ("ln2",)]
+            + [("attn", k) for k in ("wq", "wk", "wv", "wo")]
+            + [("mlp", k) for k in ("w_gate", "w_up", "w_down")])
+    if cfg.qk_norm:
+        keys += [("attn", "q_norm"), ("attn", "k_norm")]
+    for i in range(cfg.num_layers):
+        bw = {}
+        for path in keys:
+            leaf = blocks
+            for p in path:
+                leaf = leaf[p]
+            bw[path[-1]] = leaf[i]
+        out[f"block{i:03d}"] = bw
+    head_w = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
+    out["lm_head"] = {"w": head_w, "final_norm": params["final_norm"]}
+    return out
+
+
+def build_llm_graph_specs(cfg: ArchConfig) -> List[LayerSpec]:
+    """The engine layer specs of ``cfg``'s cold graph, from parameter
+    shapes alone — nothing is allocated, so this serves at any width."""
+    from repro.models import transformer as T
+
+    shapes = jax.eval_shape(lambda: _layer_weights(
+        cfg, T.init_params(jax.random.PRNGKey(0), cfg)))
+    return [LayerSpec(n, _OP_TYPES.get(n, "tblock"), {"cfg": cfg},
+                      {k: tuple(v.shape) for k, v in shapes[n].items()})
+            for n in _layer_names(cfg)]
+
+
 def build_llm_graph(cfg: ArchConfig, params) -> Tuple[List[LayerDef], np.ndarray]:
     """Convert dense-family transformer params (from T.init_params) into an
     engine graph + an example token batch. Raw storage is f32 (the master
     checkpoint); execution is bf16 (the deployed precision)."""
     assert cfg.family in ("dense",), "cold-LLM graph demo targets dense archs"
+    layers = _layer_weights(cfg, params)
     defs: List[LayerDef] = []
-
-    def f32(a):
-        return np.asarray(jnp.asarray(a, jnp.float32))
-
-    defs.append(LayerDef(
-        spec=LayerSpec("embed", "embed", {"cfg": cfg},
-                       {"embed": tuple(params["embed"].shape)}),
-        weights={"embed": f32(params["embed"])},
-    ))
-    blocks = params["blocks"]
-    for i in range(cfg.num_layers):
-        bw = {
-            "ln1": f32(blocks["ln1"][i]), "ln2": f32(blocks["ln2"][i]),
-            "wq": f32(blocks["attn"]["wq"][i]),
-            "wk": f32(blocks["attn"]["wk"][i]),
-            "wv": f32(blocks["attn"]["wv"][i]),
-            "wo": f32(blocks["attn"]["wo"][i]),
-            "w_gate": f32(blocks["mlp"]["w_gate"][i]),
-            "w_up": f32(blocks["mlp"]["w_up"][i]),
-            "w_down": f32(blocks["mlp"]["w_down"][i]),
-        }
-        if cfg.qk_norm:
-            bw["q_norm"] = f32(blocks["attn"]["q_norm"][i])
-            bw["k_norm"] = f32(blocks["attn"]["k_norm"][i])
+    for name in _layer_names(cfg):
+        w = {k: np.asarray(jnp.asarray(v, jnp.float32))
+             for k, v in layers[name].items()}
         defs.append(LayerDef(
-            spec=LayerSpec(f"block{i:03d}", "tblock", {"cfg": cfg},
-                           {k: tuple(v.shape) for k, v in bw.items()}),
-            weights=bw,
-        ))
-    head_w = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
-    defs.append(LayerDef(
-        spec=LayerSpec("lm_head", "lmhead", {"cfg": cfg},
-                       {"w": tuple(head_w.shape),
-                        "final_norm": tuple(params["final_norm"].shape)}),
-        weights={"w": f32(head_w), "final_norm": f32(params["final_norm"])},
-    ))
+            spec=LayerSpec(name, _OP_TYPES.get(name, "tblock"), {"cfg": cfg},
+                           {k: tuple(v.shape) for k, v in w.items()}),
+            weights=w))
+    return defs, example_tokens(cfg.vocab_size)
+
+
+def example_tokens(vocab_size: int, length: int = 64) -> np.ndarray:
+    """The graph's example token batch: (1, length) int32, fixed seed."""
     rng = np.random.default_rng(0)
-    x = rng.integers(0, cfg.vocab_size, size=(1, 64)).astype(np.int32)
-    return defs, x
+    return rng.integers(0, vocab_size, size=(1, length)).astype(np.int32)
+
+
+def named_llm_graph(arch: str, *, seed: int = 0, num_layers: int = 0
+                    ) -> Tuple[List[LayerDef], np.ndarray]:
+    """A registered config's cold graph at its published widths, weights
+    from ``T.init_params`` with ``seed`` (``num_layers`` > 0 cuts depth).
+    A ``FrontDoor.add_model`` builder: deterministic for a given seed."""
+    from repro.configs import get_config
+    from repro.models import transformer as T
+
+    cfg = get_config(arch)
+    if num_layers:
+        cfg = dataclasses.replace(cfg, num_layers=num_layers)
+    params = T.init_params(jax.random.PRNGKey(seed), cfg)
+    return build_llm_graph(cfg, params)
 
 
 def tiny_llm_graph(num_layers: int = 8, *, seed: int = 0

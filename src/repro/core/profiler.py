@@ -349,11 +349,12 @@ class SyntheticProfiler(Profiler):
 # ---------------------------------------------------------------------------
 def host_fingerprint() -> str:
     """Identity of the measuring host: profiles are wall-clock measurements,
-    so entries from a different machine/CPU count/jax build must miss."""
-    from repro.core.compile_cache import _version_tag
+    so entries from a different machine/CPU count/jax build/backend/device
+    kind must miss."""
+    from repro.core.compile_cache import _version_tag, device_tag
 
     parts = [platform.system(), platform.machine(),
-             str(os.cpu_count()), _version_tag()]
+             str(os.cpu_count()), _version_tag(), device_tag()]
     return hashlib.sha1("|".join(parts).encode()).hexdigest()[:16]
 
 
@@ -371,12 +372,17 @@ class ProfileDB:
     VERSION = 2
 
     def __init__(self, path: Path):
+        from repro.core.compile_cache import device_tag
+
         self.path = Path(path)
         self.host = host_fingerprint()
+        self.device = device_tag()
         # all hosts' entries are kept side by side: a shared DB file (two
         # machines, or two jax builds on one machine) must not clobber the
         # other host's profiles on save
         self._hosts: Dict[str, Dict[str, Dict[str, dict]]] = {}
+        # host fingerprint -> the backend/device kind it measured on
+        self._devices: Dict[str, str] = {}
         self.entries: Dict[str, Dict[str, dict]] = {}
         # sibling index (batch-agnostic fan-out): sibling_key -> list of
         # exact shape classes profiled under it, per host. Approximate
@@ -384,8 +390,9 @@ class ProfileDB:
         self._host_siblings: Dict[str, Dict[str, List[str]]] = {}
         self.siblings: Dict[str, List[str]] = {}
         # host-fingerprint drift: when this host has NO entries but another
-        # fingerprint in the same file does (same machine after a jax
-        # upgrade / CPU-count change), that host's entries are kept as
+        # fingerprint on the same backend and device kind in the same file
+        # does (same machine after a jax upgrade / CPU-count change), that
+        # host's entries are kept as
         # STALE fallbacks — ``get`` serves them (so the cold path never
         # pays in-line re-profiling for a fingerprint bump) and records the
         # key in ``self.stale`` so background re-profiling (the server's
@@ -410,15 +417,19 @@ class ProfileDB:
             return  # different schema: everything misses cleanly
         self._hosts = raw.get("hosts", {})
         self.entries = self._hosts.get(self.host, {})
-        # optional key: DB files from before the sibling index load fine
+        # optional keys: DB files from before the sibling index load fine
         self._host_siblings = raw.get("siblings", {})
         self.siblings = self._host_siblings.get(self.host, {})
+        self._devices = raw.get("devices", {})
         if not self.entries:
             # fingerprint drift: adopt the richest other host's entries as
             # stale estimates (measurements of the right shapes on almost
-            # this machine beat re-profiling on the cold path)
+            # this machine beat re-profiling on the cold path). Timings from
+            # another backend or device kind (or of unknown device) are
+            # never adopted: a CPU profile must not plan a TPU run
             donors = [h for h in self._hosts if h != self.host
-                      and self._hosts[h]]
+                      and self._hosts[h]
+                      and self._devices.get(h) == self.device]
             if donors:
                 self.drifted_from = max(
                     donors, key=lambda h: sum(len(v) for v
@@ -480,6 +491,7 @@ class ProfileDB:
         if not self._dirty:
             return
         self._hosts[self.host] = self.entries
+        self._devices[self.host] = self.device
         if self.siblings:
             self._host_siblings[self.host] = self.siblings
         self.path.parent.mkdir(parents=True, exist_ok=True)
@@ -487,6 +499,6 @@ class ProfileDB:
         # substrate — a torn file would silently force a full reprofile
         atomic_write_text(self.path, json.dumps({
             "version": self.VERSION, "hosts": self._hosts,
-            "siblings": self._host_siblings}, indent=1),
-            durable=True)
+            "siblings": self._host_siblings, "devices": self._devices},
+            indent=1), durable=True)
         self._dirty = False

@@ -46,6 +46,7 @@ then ``cold_start`` → ``result``/``error`` interleaved with
 """
 from __future__ import annotations
 
+import glob
 import os
 import pickle
 import socket
@@ -170,6 +171,24 @@ class FrontDoorRequest:
         return self._result
 
 
+def allotted_chips() -> Optional[List[str]]:
+    """The TPU chips a front door may hand to its workers, one each: the
+    parent's own ``TPU_VISIBLE_CHIPS`` when it is set, else one per chip
+    device node the host exposes (``/dev/accel<n>``, ``/dev/vfio/<n>``; a
+    host may list more chips on its PCI bus than it lets this machine
+    open). ``None`` where the workers use no TPU: ``JAX_PLATFORMS`` leaves
+    it out, or the host exposes none."""
+    platforms = os.environ.get("JAX_PLATFORMS")
+    if platforms and "tpu" not in platforms.split(","):
+        return None
+    visible = os.environ.get("TPU_VISIBLE_CHIPS")
+    if visible:
+        return [c.strip() for c in visible.split(",") if c.strip()]
+    nodes = [p for p in glob.glob("/dev/accel*") + glob.glob("/dev/vfio/*")
+             if os.path.basename(p).removeprefix("accel").isdigit()]
+    return [str(i) for i in range(len(nodes))] or None
+
+
 class _Worker:
     """Supervisor-side state for one worker process."""
 
@@ -190,6 +209,8 @@ class _Worker:
         self.ready_models: set = set()
         self.model_ready_evt: Dict[str, threading.Event] = {}
         self.hello_evt = threading.Event()
+        self.device: Dict[str, Any] = {}       # as the worker's JAX sees it
+        self.chip_port: Optional[int] = None   # TPU runtime port (restarts keep it)
 
     def capacity(self, max_inflight: int) -> int:
         return max(0, max_inflight - len(self.in_flight)) if self.alive else 0
@@ -230,6 +251,7 @@ class FrontDoor:
         # correctness invariant)
         self.profile_db_path = self.root / "profile_db.json"
         self.repairs = RepairLog(self.root / "frontdoor_repairs.jsonl")
+        self.chips = allotted_chips()
 
         self._lock = threading.Lock()
         self._dispatch_cv = threading.Condition(self._lock)
@@ -256,19 +278,52 @@ class FrontDoor:
 
     # -- lifecycle -----------------------------------------------------------
     def start(self) -> "FrontDoor":
+        """Spawn every worker and wait for its hello. A worker that exits
+        first (e.g. it cannot initialise its device) fails ``start()`` at
+        once with that worker's own error output; the others are stopped."""
+        if self.chips is not None and self.n_workers > len(self.chips):
+            raise RuntimeError(
+                f"{self.n_workers} workers need one TPU chip each, but this "
+                f"front door may use {len(self.chips)} "
+                f"(chips {','.join(self.chips) or 'none'})")
         self._listener = socket.socket(socket.AF_INET, socket.SOCK_STREAM)
         self._listener.bind(("127.0.0.1", 0))
         self._listener.listen(self.n_workers * 2)
         self._port = self._listener.getsockname()[1]
         self._spawn_thread("fd-accept", self._accept_loop)
-        for w in self._workers.values():
-            self._spawn_worker(w)
-        for w in self._workers.values():
-            if not w.hello_evt.wait(self.spawn_timeout_s):
-                raise RuntimeError(f"worker {w.wid} never said hello")
+        try:
+            for w in self._workers.values():
+                self._spawn_worker(w)
+            deadline = time.monotonic() + self.spawn_timeout_s
+            for w in self._workers.values():
+                while not w.hello_evt.wait(0.05):
+                    rc = w.proc.poll()
+                    if rc is not None:
+                        raise RuntimeError(
+                            f"worker {w.wid} exited with code {rc} before "
+                            f"saying hello:\n{self._log_tail(w)}")
+                    if time.monotonic() > deadline:
+                        raise RuntimeError(
+                            f"worker {w.wid} never said hello within "
+                            f"{self.spawn_timeout_s:.0f}s:\n"
+                            f"{self._log_tail(w)}")
+        except BaseException:
+            self.shutdown(drain_timeout_s=1.0)
+            raise
         self._spawn_thread("fd-dispatch", self._dispatch_loop)
         self._spawn_thread("fd-supervisor", self._supervise_loop)
         return self
+
+    def worker_log(self, wid: str) -> Path:
+        """The worker's stderr (appended across restarts)."""
+        return self.root / wid / "worker.log"
+
+    def _log_tail(self, w: _Worker, nbytes: int = 4000) -> str:
+        try:
+            data = self.worker_log(w.wid).read_bytes()
+        except OSError:
+            return "(no worker log)"
+        return data[-nbytes:].decode(errors="replace")
 
     def _spawn_thread(self, name, target):
         t = threading.Thread(target=target, name=name, daemon=True)
@@ -282,6 +337,7 @@ class FrontDoor:
         src = str(Path(list(repro.__path__)[0]).resolve().parent)
         env = dict(os.environ)
         env["PYTHONPATH"] = src + os.pathsep + env.get("PYTHONPATH", "")
+        env.update(self._chip_env(w))
         argv = [sys.executable, "-m", "repro.executor.worker",
                 "--host", "127.0.0.1", "--port", str(self._port),
                 "--worker-id", w.wid, "--root", str(wroot),
@@ -290,9 +346,28 @@ class FrontDoor:
         for k, v in self.worker_args.items():
             argv += [f"--{k.replace('_', '-')}", str(v)]
         w.hello_evt.clear()
-        w.proc = subprocess.Popen(argv, env=env,
-                                  stdout=subprocess.DEVNULL,
-                                  stderr=subprocess.DEVNULL)
+        with open(self.worker_log(w.wid), "ab") as log:
+            w.proc = subprocess.Popen(argv, env=env,
+                                      stdout=subprocess.DEVNULL,
+                                      stderr=log)
+
+    def _chip_env(self, w: _Worker) -> Dict[str, str]:
+        """One chip per worker: the TPU runtime shows worker ``w<i>`` the
+        i-th of :attr:`chips` alone (a one-chip process bound inside the
+        host's chips, so the runtime lets the processes load side by
+        side). Nothing is set where the workers use no TPU."""
+        if self.chips is None:
+            return {}
+        chip = self.chips[list(self._workers).index(w.wid)]
+        if w.chip_port is None:
+            with socket.socket() as s:     # the runtime's own local port
+                s.bind(("127.0.0.1", 0))
+                w.chip_port = s.getsockname()[1]
+        return {"TPU_VISIBLE_CHIPS": chip,
+                "TPU_CHIPS_PER_PROCESS_BOUNDS": "1,1,1",
+                "TPU_PROCESS_BOUNDS": "1,1,1",
+                "TPU_PROCESS_PORT": str(w.chip_port),
+                "TPU_PROCESS_ADDRESSES": f"localhost:{w.chip_port}"}
 
     def _accept_loop(self):
         while not self._shutdown:
@@ -318,6 +393,7 @@ class FrontDoor:
                 w.alive = True
                 w.last_heartbeat = time.monotonic()
                 w.warm_port = hello.get("warm_port")
+                w.device = hello.get("device") or {}
                 w.down_at = None
                 w.restart_due = None
             threading.Thread(target=self._recv_loop, args=(w, sock),
@@ -736,6 +812,7 @@ class FrontDoor:
                         "last_restart_delay": w.last_restart_delay,
                         "resident": list((w.health or {}).get(
                             "resident") or []),
+                        "device": dict(w.device),
                     } for w in self._workers.values()},
             }
 

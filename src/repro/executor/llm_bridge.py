@@ -51,6 +51,10 @@ class ColdLLMResult:
     overlapped_layers: int            # preps still unfinished at first execute
     overlapped_packs: int             # packs started before the exec chain ended
     run: RunResult = field(repr=False, default=None)
+    # (len(tokens), V) float32: row t is the logits tokens[t] was picked
+    # from (row 0 from the streamed prefill, the rest from decode through
+    # the KV cache); only with ``keep_logits=True``
+    logits: Optional[np.ndarray] = field(repr=False, default=None)
 
     @property
     def first_token_before_last_prep(self) -> bool:
@@ -110,6 +114,7 @@ def cold_start_llm(
     n_little: int = 3,
     server: Optional[Any] = None,     # ColdServer for admission (optional)
     model_name: Optional[str] = None,
+    keep_logits: bool = False,
 ) -> ColdLLMResult:
     """Cold-start a ``build_llm_graph`` engine and serve ``max_new_tokens``
     greedily; see the module docstring for the pipeline."""
@@ -176,10 +181,12 @@ def cold_start_llm(
                         budget=(server.budget if server is not None
                                 else None))
     tokens = [first_token]
+    rows = [logits[0, -1]]
     if max_new_tokens > 1:
         req = Request(rid=0,
                       prompt=np.concatenate([prompt, [first_token]]),
-                      max_new_tokens=max_new_tokens - 1)
+                      max_new_tokens=max_new_tokens - 1,
+                      out_logits=[] if keep_logits else None)
         srv.submit(req)
         srv.step()       # admit: replays the prompt into the KV slot
         # decode-ready = params stacked + KV slot prefilled (NOT the full
@@ -188,6 +195,7 @@ def cold_start_llm(
         srv.run_until_drained()
         assert req.done_s is not None, "decode did not drain"
         tokens += [int(tk) for tk in req.out_tokens]
+        rows += req.out_logits or []
     else:
         decode_ready_s = time.perf_counter() - job.t0
     srv.close()     # return the KV reservation to the shared budget
@@ -198,5 +206,5 @@ def cold_start_llm(
         last_weight_prep_s=last_weight_prep_s,
         decode_prep_s=decode_prep_s, decode_ready_s=decode_ready_s,
         overlapped_layers=overlapped, overlapped_packs=overlapped_packs,
-        run=res,
+        run=res, logits=np.stack(rows) if keep_logits else None,
     )

@@ -47,6 +47,27 @@ def _build(spec):
     return fn(**(spec.get("kwargs") or {}))
 
 
+def chip_nodes() -> list:
+    """The accelerator device nodes this process holds open, as the kernel
+    names them (``/dev/accel<n>``, ``/dev/vfio/<group>``): once the backend
+    has started, the chips it took. Empty off Linux or without a chip."""
+    nodes = set()
+    try:
+        fds = os.listdir("/proc/self/fd")
+    except OSError:
+        return []
+    for fd in fds:
+        try:
+            target = os.readlink(f"/proc/self/fd/{fd}")
+        except OSError:
+            continue
+        if target.startswith("/dev/accel") or (
+                target.startswith("/dev/vfio/")
+                and target != "/dev/vfio/vfio"):
+            nodes.add(target)
+    return sorted(nodes)
+
+
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser()
     ap.add_argument("--host", default="127.0.0.1")
@@ -85,9 +106,22 @@ def main(argv=None) -> int:
     # warm the JAX backend now, not inside the first request: lazy backend
     # init costs ~300ms and would otherwise land inside the first cold
     # start's submit path — dwarfing the job itself and skewing the
-    # warm-state race (the peer stream would start ~300ms late)
+    # warm-state race (the peer stream would start ~300ms late). A device
+    # that cannot initialise fails the process here, before the hello, so
+    # the front door's start() reports this worker's error
+    import jax
     import jax.numpy as jnp
+
+    from repro.core.compile_cache import setup_compile_cache
+
+    setup_compile_cache()
     jnp.zeros(()).block_until_ready()
+    dev = jax.devices()[0]
+    device = {"platform": dev.platform, "kind": dev.device_kind,
+              "count": len(jax.devices()), "id": dev.id,
+              "coords": list(getattr(dev, "coords", None) or []),
+              "visible_chips": os.environ.get("TPU_VISIBLE_CHIPS"),
+              "nodes": chip_nodes()}
 
     pool = CorePool(n_little=args.n_little, n_big=args.n_big,
                     pin_cores=args.pin_cores)
@@ -101,7 +135,8 @@ def main(argv=None) -> int:
     sock.setsockopt(socket.IPPROTO_TCP, socket.TCP_NODELAY, 1)
     send_lock = threading.Lock()
     send_msg(sock, {"type": "hello", "worker": args.worker_id,
-                    "pid": os.getpid(), "warm_port": warm.port}, send_lock)
+                    "pid": os.getpid(), "warm_port": warm.port,
+                    "device": device}, send_lock)
 
     examples = {}          # model -> x_example (for restart-side decide)
     stop = threading.Event()

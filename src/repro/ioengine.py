@@ -245,7 +245,7 @@ class PinnedBufferPool:
 
 class _Request:
     __slots__ = ("fd", "offset", "nbytes", "buf", "key", "event", "error",
-                 "engine", "token", "abandoned", "ready_at")
+                 "engine", "token", "abandoned", "ready_at", "on_done")
 
     def __init__(self, engine: "IOEngine", fd: int, offset: int, nbytes: int,
                  buf: PinnedBuffer, key: Optional[str]):
@@ -260,6 +260,7 @@ class _Request:
         self.token = 0
         self.abandoned = False
         self.ready_at = 0.0    # disk-emulation pacing (sim_read_bytes_per_s)
+        self.on_done: Optional[Callable[[], None]] = None
 
     def finish(self, error: Optional[BaseException] = None) -> None:
         self.error = error
@@ -267,6 +268,8 @@ class _Request:
         self.event.set()
         if self.abandoned:
             self.buf.release()  # idempotent; see PinnedBufferPool._release
+        if self.on_done is not None:
+            self.on_done()      # the backend is done with ``fd``
 
 
 def _read_fully(req: _Request) -> Optional[BaseException]:
@@ -790,10 +793,13 @@ class IOEngine:
 
     # -- submit / reap ------------------------------------------------------
     def submit(self, fd: int, offset: int, nbytes: int, *,
-               key: Optional[str] = None, injector=None) -> ReadTicket:
+               key: Optional[str] = None, injector=None,
+               on_done: Optional[Callable[[], None]] = None) -> ReadTicket:
         """Queue one read.  Blocks while the bytes-in-flight budget is
         exhausted (an oversized single request is admitted when the
-        engine is otherwise empty, so the gate can never wedge)."""
+        engine is otherwise empty, so the gate can never wedge).
+        ``on_done`` runs once the backend has finished with ``fd`` for
+        this read — only when ``submit`` returned a ticket."""
         if injector is not None:
             injector.maybe_fault("ioengine.submit", key)
         nbytes = int(nbytes)
@@ -822,9 +828,11 @@ class IOEngine:
         buf = self.pool.acquire(nbytes)
         req = _Request(self, fd, offset, nbytes, buf, key)
         req.ready_at = ready_at
+        req.on_done = on_done
         try:
             self.backend.submit(req)
         except BaseException as e:
+            req.on_done = None
             buf.release()
             self._on_complete(req)
             if isinstance(e, OSError):
@@ -1004,11 +1012,10 @@ class StageEngine:
 
     @staticmethod
     def _accelerator_present() -> bool:
-        try:
-            import jax
-            return jax.devices()[0].platform not in ("cpu",)
-        except Exception:
-            return False
+        # no try/except: a backend that fails to initialise must surface
+        # here, not quietly select the host path
+        import jax
+        return jax.default_backend() != "cpu"
 
     # -- host path ----------------------------------------------------------
     def _stage_host(self, w: Dict[str, Any]) -> Dict[str, Any]:

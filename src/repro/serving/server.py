@@ -57,6 +57,8 @@ class Request:
     submitted_s: float = 0.0
     first_token_s: Optional[float] = None
     done_s: Optional[float] = None
+    # set to [] to keep the float32 logits row each output token came from
+    out_logits: Optional[List[np.ndarray]] = None
 
 
 class BatchedServer:
@@ -93,6 +95,8 @@ class BatchedServer:
         self._key = jax.random.PRNGKey(0)
 
     def _pick(self, req: Request, logits_row: jax.Array) -> int:
+        if req.out_logits is not None:
+            req.out_logits.append(np.asarray(logits_row, np.float32))
         self._key, sub = jax.random.split(self._key)
         return int(sample_token(
             logits_row, sub, temperature=req.temperature,

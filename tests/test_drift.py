@@ -1,8 +1,10 @@
 """Host-fingerprint drift: a ProfileDB measured under another fingerprint
-(same machine after a jax upgrade / CPU-count change) serves its entries
-as STALE fallbacks — the cold path never re-profiles in-line — and the
-background path (``ColdEngine.reprofile_stale``, driven by the server's
-idle tick) re-measures them off the request path."""
+on the same backend and device kind (same machine after a jax upgrade /
+CPU-count change) serves its entries as STALE fallbacks — the cold path
+never re-profiles in-line — and the background path
+(``ColdEngine.reprofile_stale``, driven by the server's idle tick)
+re-measures them off the request path. Entries measured on another backend
+or device kind are never adopted: they miss."""
 import json
 
 import pytest
@@ -20,11 +22,14 @@ def _prof(layer="l0", kernel="k"):
                      compile_s=0.3, raw_bytes=100, transformed_bytes=80)
 
 
-def _drift_db_file(path):
-    """Rewrite a saved DB as if every entry was measured on another host."""
+def _drift_db_file(path, device=None):
+    """Rewrite a saved DB as if every entry was measured on another host
+    (of the same backend and device kind unless ``device`` names another)."""
     raw = json.loads(path.read_text())
     raw["hosts"] = {FAKE_HOST: v for v in raw["hosts"].values()}
     raw["siblings"] = {FAKE_HOST: v for v in raw.get("siblings", {}).values()}
+    raw["devices"] = {FAKE_HOST: device or v
+                      for v in raw.get("devices", {}).values()}
     path.write_text(json.dumps(raw))
 
 
@@ -53,6 +58,30 @@ def test_drifted_entries_serve_stale_and_unstale_on_put(tmp_path):
     db2.save()
     hosts = json.loads(p.read_text())["hosts"]
     assert FAKE_HOST in hosts and db2.host in hosts
+
+
+@pytest.mark.parametrize("device", ["tpu/TPU v5 lite", "gpu/H100"])
+def test_other_device_entries_never_adopted(tmp_path, device):
+    """Timings measured on another backend or device kind are not drift of
+    this host: they miss, and decide() profiles afresh."""
+    p = tmp_path / "db.json"
+    db = ProfileDB(p)
+    db.put("sc1", "k", _prof())
+    db.save()
+    _drift_db_file(p, device=device)
+
+    db2 = ProfileDB(p)
+    assert db2.drifted_from is None
+    assert db2.get("sc1", "k") is None
+    assert db2.stats == {"hits": 0, "misses": 1, "approx_hits": 0,
+                         "stale_hits": 0}
+    assert db2.stale == set()
+    # the foreign device's entries survive a save side by side
+    db2.put("sc1", "k", _prof())
+    db2.save()
+    raw = json.loads(p.read_text())
+    assert raw["devices"][FAKE_HOST] == device
+    assert raw["devices"][db2.host] == db2.device
 
 
 def test_no_drift_adoption_when_current_host_has_entries(tmp_path):

@@ -219,3 +219,72 @@ def test_frontdoor_chaos_end_to_end(tmp_path):
                                       np.asarray(res["output"]))
     finally:
         fd.shutdown()
+
+
+def test_worker_start_failure_raises_with_its_error(tmp_path, monkeypatch):
+    """A worker whose device cannot initialise exits before its hello:
+    start() fails within seconds, not after the spawn timeout, and carries
+    that worker's own error text (its stderr lands in a log under its
+    root); every spawned worker is stopped."""
+    monkeypatch.setenv("JAX_PLATFORMS", "no_such_platform")
+    fd = FrontDoor(tmp_path / "fd", n_workers=2, spawn_timeout_s=120.0)
+    t0 = time.monotonic()
+    with pytest.raises(RuntimeError) as ei:
+        fd.start()
+    assert time.monotonic() - t0 < 60.0
+    msg = str(ei.value)
+    assert "before saying hello" in msg and "no_such_platform" in msg
+    assert "no_such_platform" in fd.worker_log("w0").read_text()
+    for w in fd._workers.values():
+        assert w.proc is not None and w.proc.poll() is not None
+
+
+def test_workers_get_one_chip_each(tmp_path, monkeypatch):
+    """Each worker process is shown its own chip through the TPU runtime's
+    per-process visibility, taken in order from the chips the parent itself
+    may use; a restart keeps the same chip and port."""
+    monkeypatch.delenv("JAX_PLATFORMS", raising=False)
+    monkeypatch.setenv("TPU_VISIBLE_CHIPS", "4,5,6,7")
+    fd = FrontDoor(tmp_path / "fd", n_workers=4)
+    envs = [fd._chip_env(w) for w in fd._workers.values()]
+    assert [e["TPU_VISIBLE_CHIPS"] for e in envs] == ["4", "5", "6", "7"]
+    assert all(e["TPU_CHIPS_PER_PROCESS_BOUNDS"] == "1,1,1" for e in envs)
+    assert len({e["TPU_PROCESS_PORT"] for e in envs}) == 4
+    assert fd._chip_env(fd._workers["w2"]) == envs[2]
+
+
+def test_more_workers_than_chips_fails_at_start(tmp_path, monkeypatch):
+    """A front door allotted fewer chips than it has workers refuses to
+    start, naming the chips, before it spawns anything; with no TPU in
+    ``JAX_PLATFORMS`` the workers get no chip binding at all."""
+    monkeypatch.delenv("JAX_PLATFORMS", raising=False)
+    monkeypatch.setenv("TPU_VISIBLE_CHIPS", "2,3")
+    fd = FrontDoor(tmp_path / "fd", n_workers=3)
+    with pytest.raises(RuntimeError, match=r"3 workers .* may use 2 "
+                                           r"\(chips 2,3\)"):
+        fd.start()
+    assert all(w.proc is None for w in fd._workers.values())
+
+    monkeypatch.setenv("JAX_PLATFORMS", "cpu")
+    cpu = FrontDoor(tmp_path / "cpu", n_workers=3)
+    assert cpu.chips is None
+    assert all(cpu._chip_env(w) == {} for w in cpu._workers.values())
+
+
+def test_chips_counted_from_device_nodes(monkeypatch):
+    """Without ``TPU_VISIBLE_CHIPS`` the chips are the device nodes the host
+    exposes (``/dev/vfio/vfio`` is the VFIO container, not a chip): a
+    machine that opens one chip of a four-chip board gets one worker."""
+    from repro.executor import frontdoor as fdm
+
+    monkeypatch.delenv("JAX_PLATFORMS", raising=False)
+    monkeypatch.delenv("TPU_VISIBLE_CHIPS", raising=False)
+    nodes = {"/dev/accel*": [], "/dev/vfio/*": ["/dev/vfio/0",
+                                                 "/dev/vfio/vfio"]}
+    monkeypatch.setattr(fdm.glob, "glob", lambda pat: nodes[pat])
+    assert fdm.allotted_chips() == ["0"]
+    nodes["/dev/accel*"] = [f"/dev/accel{i}" for i in range(4)]
+    nodes["/dev/vfio/*"] = []
+    assert fdm.allotted_chips() == ["0", "1", "2", "3"]
+    nodes["/dev/accel*"] = []
+    assert fdm.allotted_chips() is None

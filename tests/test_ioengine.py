@@ -325,6 +325,39 @@ def test_async_corrupt_cache_extent_drops_and_reports(tmp_path):
 # fault injection at the engine sites: bounded retries, typed faults,
 # nothing leaked at shutdown
 # ---------------------------------------------------------------------------
+def test_bundle_close_leaves_fd_to_in_flight_reads(tmp_path, monkeypatch):
+    """Closing a super-bundle while the engine still owes reads on its fd
+    defers the close to the last of them: the reads land clean bytes, never
+    EBADF or another file's bytes, and the fd closes once they finish."""
+    store, want = _store_with_layers(tmp_path, "super")
+    gate = threading.Event()
+    real = iomod._read_fully
+
+    def gated(req):
+        gate.wait(10.0)
+        return real(req)
+
+    eng = IOEngine(backend="aio")   # its self-check reads ungated
+    monkeypatch.setattr(iomod, "_read_fully", gated)
+    try:
+        pend = store.submit_read_raw(eng, "l0")
+        sb = pend.sb
+        store.close()               # every read is still behind the gate
+        assert sb._fd is not None
+        with pytest.raises(RuntimeError, match="closed bundle"):
+            sb.submit_read(eng, "l1")
+        gate.set()
+        got = pend.wait(10.0)
+        for k, v in want["l0"].items():
+            assert np.array_equal(np.asarray(got[k]), v)
+        pend.release()
+        assert eng.drain(10.0)
+        assert sb._fd is None
+    finally:
+        gate.set()
+        eng.close()
+
+
 @pytest.mark.parametrize("site", ["ioengine.submit", "ioengine.reap"])
 def test_injected_engine_fault_is_typed_and_retryable(tmp_path, site):
     store, want = _store_with_layers(tmp_path, "super")
@@ -350,6 +383,9 @@ def test_injected_engine_fault_is_typed_and_retryable(tmp_path, site):
             assert np.array_equal(np.asarray(got[k]), v)
         h.release()
         assert inj.injected and inj.injected[0]["site"] == site
+        # a faulted attempt's abandoned reads may still be on a backend
+        # worker; nothing leaks once they finish
+        assert eng.drain(10.0)
         snap = eng.snapshot()
         assert snap["in_flight"] == 0 and snap["bytes_in_flight"] == 0
     finally:

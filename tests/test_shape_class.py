@@ -177,7 +177,10 @@ def test_one_compile_per_shape_class(llm_graph, tmp_path):
     eng._jitted_map(eng.plan.choices, toks)
     pairs = {(eng._sc_by_layer[l.spec.name], c.kernel)
              for l, c in zip(eng.layers, eng.plan.choices)}
-    assert eng.compile_cache.stats["misses"] == len(pairs)
+    # one executable per pair: compiled here, or loaded from the shared
+    # on-disk cache where an earlier run already compiled it
+    s = eng.compile_cache.stats
+    assert s["misses"] + s["disk_hits"] == len(pairs)
     # the N identical tblocks share ONE executable object
     jitted = eng._jitted_map(eng.plan.choices, toks)
     tbl = [jitted[l.spec.name] for l in eng.layers
@@ -220,6 +223,39 @@ def test_compile_cache_version_guard(tmp_path):
         assert cache3.stats["misses"] == 1 and cache3.stats["disk_hits"] == 0
     finally:
         cc._version_tag = orig
+
+    # ...and so must one built for another backend or device kind
+    orig = cc.device_tag
+    cc.device_tag = lambda: "tpu/TPU v5 lite"
+    try:
+        cache4 = cc.CompileCache(tmp_path)
+        cache4.get("k", spec, fn, w, x, shape_class="sc")
+        assert cache4.stats["misses"] == 1 and cache4.stats["disk_hits"] == 0
+    finally:
+        cc.device_tag = orig
+
+    # an edited kernel body under the same kernel name and shape class is
+    # another program: it misses on disk instead of loading the old one
+    cache_e = cc.CompileCache(tmp_path)
+    edited = cache_e.get("k", spec, lambda w, x: x @ w["w"] + 1.0, w, x,
+                         shape_class="sc")
+    assert cache_e.stats["misses"] == 1 and cache_e.stats["disk_hits"] == 0
+    assert float(edited(w, x)[0, 0]) == 5.0
+    cache_o = cc.CompileCache(tmp_path)
+    orig_fn = cache_o.get("k", spec, fn, w, x, shape_class="sc")
+    assert cache_o.stats["disk_hits"] == 1
+    assert float(orig_fn(w, x)[0, 0]) == 4.0
+
+    # an unreadable entry is counted, recompiled and rewritten
+    for f in tmp_path.glob("*.xla"):
+        f.write_bytes(b"not a pickle")
+    cache5 = cc.CompileCache(tmp_path)
+    cache5.get("k", spec, fn, w, x, shape_class="sc")
+    assert cache5.stats["deserialize_failures"] == 1
+    assert cache5.stats["misses"] == 1 and cache5.last_error
+    cache6 = cc.CompileCache(tmp_path)
+    cache6.get("k", spec, fn, w, x, shape_class="sc")
+    assert cache6.stats["disk_hits"] == 1
 
 
 def test_cache_invalidated_on_weight_update(tmp_path):
