@@ -110,12 +110,6 @@ def run_cold_llm(failures: list, *, num_layers=6):
     server.decide("llm", toks, n_little=2)
     res = cold_start_llm(eng, cfg, toks[0], max_new_tokens=4, n_little=2,
                          server=server, model_name="llm")
-    # policy invariant (pack deps must keep packing off the exec chain —
-    # a dep regression flips this), not overlap evidence by itself
-    _gate(res.first_token_before_last_prep,
-          f"cold_llm: first token ({res.first_token_s*1e3:.0f} ms) before "
-          f"last layer decode prep ({res.decode_prep_s*1e3:.0f} ms) "
-          f"[scheduling-policy invariant]", failures)
     # the actual overlap evidence: execute-as-you-load
     _gate(res.overlapped_layers >= 1,
           f"cold_llm: {res.overlapped_layers} weight-prep ops still in "
@@ -196,10 +190,6 @@ def run_quantized_llm(failures: list, *, num_layers=6):
     _gate(corr > 0.75,
           f"quantized_llm: prefill logits correlate with the bf16 arm "
           f"(corr {corr:.4f} > 0.75)", failures)
-    _gate(q["res"].first_token_before_last_prep,
-          f"quantized_llm: first token ({q['res'].first_token_s*1e3:.0f} "
-          f"ms) still beats the last decode prep on the quantized path "
-          f"({q['res'].decode_prep_s*1e3:.0f} ms)", failures)
     print(f"serving/quantized_llm/bytes_ratio,{ratio:.4f},")
     print(f"serving/quantized_llm/ttft_ratio,"
           f"{q['ttft'] / max(1e-9, arms['bf16']['ttft']):.4f},")

@@ -86,27 +86,6 @@ def require_tpu():
     return devs
 
 
-class CompileCounter:
-    """Backend compiles and persistent-cache hits, from JAX's own events."""
-
-    def __init__(self):
-        import jax
-
-        self.n, self.s, self.pcache_hits = 0, 0.0, 0
-
-        def on_duration(event, secs, **_):
-            if event == "/jax/core/compile/backend_compile_duration":
-                self.n += 1
-                self.s += secs
-
-        def on_event(event, **_):
-            if event == "/jax/compilation_cache/cache_hits":
-                self.pcache_hits += 1
-
-        jax.monitoring.register_event_duration_secs_listener(on_duration)
-        jax.monitoring.register_event_listener(on_event)
-
-
 def rel_err(got, ref) -> float:
     got, ref = np.asarray(got, np.float64), np.asarray(ref, np.float64)
     return float(np.abs(got - ref).max() / np.abs(ref).max())
@@ -139,7 +118,6 @@ def serve_and_check(cfg, workdir: Path) -> None:
     from repro.executor.server import ColdServer
     from repro.models import transformer as T
 
-    compiles = CompileCounter()
     t0 = time.perf_counter()
     params = T.init_params(jax.random.PRNGKey(SEED), cfg)
     graph, toks = build_llm_graph(cfg, params)
@@ -165,16 +143,18 @@ def serve_and_check(cfg, workdir: Path) -> None:
             server.evict(MODEL)
             check(MODEL not in server.resident_models(),
                   f"request {i}: model evicted, so this start is cold")
-        n0, s0 = compiles.n, compiles.s
         res = cold_start_llm(eng, cfg, prompt, max_new_tokens=NEW_TOKENS,
                              n_little=3, server=server, model_name=MODEL,
                              keep_logits=True)
         stages = {k: round(v, 4)
                   for k, v in res.run.stage_seconds().items()}
+        backend = [t for t in res.spans if t.kind == "compile"
+                   and t.detail.startswith("backend_compile")]
         print(f"request {i}: first token {res.first_token_s:.4f}s, decode "
               f"ready {res.decode_ready_s:.4f}s, stage seconds {stages}, "
-              f"backend compiles {compiles.n - n0} "
-              f"({compiles.s - s0:.2f}s), tokens {res.tokens}", flush=True)
+              f"backend compiles or cache loads {len(backend)} "
+              f"({sum(t.end - t.start for t in backend):.2f}s), tokens "
+              f"{res.tokens}", flush=True)
         check(MODEL in server.resident_models(),
               f"request {i}: staged weights resident after the cold start")
         results.append(res)
@@ -223,9 +203,8 @@ def serve_and_check(cfg, workdir: Path) -> None:
           f"({st_eng.stats if st_eng else {}})", flush=True)
     print(f"executable cache: {cc['misses']} compiled "
           f"({cc['compile_s']:.2f}s), {cc['disk_hits']} from disk, "
-          f"{cc['hits']} in memory, {cc['deserialize_failures']} unreadable; "
-          f"process backend compiles {compiles.n} ({compiles.s:.2f}s), "
-          f"persistent-cache hits {compiles.pcache_hits}", flush=True)
+          f"{cc['hits']} in memory, {cc['deserialize_failures']} unreadable",
+          flush=True)
 
 
 def four_chips(workdir: Path) -> dict:

@@ -51,7 +51,6 @@ def main():
         print(f"last weight prep {res.last_weight_prep_s*1e3:.0f} ms | "
               f"last layer decode prep {res.decode_prep_s*1e3:.0f} ms | "
               f"decode ready {res.decode_ready_s*1e3:.0f} ms")
-        assert res.first_token_before_last_prep
         print(f"tokens: {res.tokens}")
 
         cold = res.run                            # pipelined weight streaming

@@ -36,11 +36,12 @@ import functools
 import hashlib
 import os
 import pickle
-import time
 from pathlib import Path
 from typing import Callable, Dict, Optional, Tuple
 
 import jax
+
+from repro.executor.graph import span
 
 ENV_CACHE_DIR = "JAX_COMPILATION_CACHE_DIR"
 # src/repro/core/compile_cache.py -> the checkout root
@@ -121,30 +122,37 @@ class CompileCache:
             self.stats["hits"] += 1
             return self.mem[key]
         # jax.jit is only built past the in-memory check, where a hit is
-        # free; a disk hit pays the lowering, as its name needs the program
-        t0 = time.perf_counter()
-        lowered = jax.jit(fn).lower(w_example, x_example)
-        path = None
-        if self.root:
-            code = hashlib.sha1(lowered.as_text().encode()).hexdigest()
-            path = self.root / f"{_key(key, code, (), '', '')}.xla"
-        if path and path.exists():
-            try:
-                from jax.experimental import serialize_executable as se
+        # free; a disk hit pays the lowering, as its name needs the
+        # program. The time is a ``compile`` span of the request (if any)
+        # whose thread got here; the stats read the same span.
+        with span("compile", layer=spec.name,
+                  detail=f"executable_cache {kernel_name}") as sp:
+            lowered = jax.jit(fn).lower(w_example, x_example)
+            path = compiled = None
+            if self.root:
+                code = hashlib.sha1(lowered.as_text().encode()).hexdigest()
+                path = self.root / f"{_key(key, code, (), '', '')}.xla"
+            if path and path.exists():
+                try:
+                    from jax.experimental import serialize_executable as se
 
-                with open(path, "rb") as f:
-                    payload = pickle.load(f)
-                compiled = se.deserialize_and_load(*payload)
-                self.stats["deserialize_s"] += time.perf_counter() - t0
-                self.stats["disk_hits"] += 1
-                self.mem[key] = compiled
-                return compiled
-            except Exception as e:
-                # unreadable entry: counted, then recompiled and rewritten
-                self.stats["deserialize_failures"] += 1
-                self.last_error = f"deserialize {path.name}: {e!r}"
-        compiled = lowered.compile()
-        self.stats["compile_s"] += time.perf_counter() - t0
+                    with open(path, "rb") as f:
+                        payload = pickle.load(f)
+                    compiled = se.deserialize_and_load(*payload)
+                except Exception as e:
+                    # unreadable entry: counted, then recompiled and
+                    # rewritten
+                    self.stats["deserialize_failures"] += 1
+                    self.last_error = f"deserialize {path.name}: {e!r}"
+            loaded = compiled is not None
+            if not loaded:
+                compiled = lowered.compile()
+        if loaded:
+            self.stats["deserialize_s"] += sp.seconds
+            self.stats["disk_hits"] += 1
+            self.mem[key] = compiled
+            return compiled
+        self.stats["compile_s"] += sp.seconds
         self.stats["misses"] += 1
         if path:
             try:

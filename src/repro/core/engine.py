@@ -674,8 +674,8 @@ class ColdEngine:
                 w_ex = {}
             xin = np.asarray(xin)
             x_ex = jax.ShapeDtypeStruct(tuple(xin.shape), xin.dtype)
-            fn = (lambda kern, spec: lambda w, x: kern.execute(w, x, spec))(kern, l.spec)
-            compiled = self.compile_cache.get(kern.name, l.spec, fn, w_ex,
+            compiled = self.compile_cache.get(kern.name, l.spec,
+                                              kern.program(l.spec), w_ex,
                                               x_ex, shape_class=sc)
             jitted[l.spec.name] = compiled
         self._jitted_cache[key] = jitted

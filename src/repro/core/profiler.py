@@ -207,7 +207,7 @@ class Profiler:
             t_stage = 0.0
         wj = {k: jnp.asarray(v) for k, v in transformed.items()}
         xj = jnp.asarray(x)
-        fn = jax.jit(lambda w, x: kernel.execute(w, x, spec))
+        fn = jax.jit(kernel.program(spec))
         t0 = time.perf_counter()
         y = fn(wj, xj)
         jax.block_until_ready(y)

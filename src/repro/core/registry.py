@@ -154,6 +154,16 @@ class Kernel:
     def execute(self, w: Dict[str, jnp.ndarray], x: jnp.ndarray, spec: LayerSpec) -> jnp.ndarray:
         raise NotImplementedError
 
+    def program(self, spec: LayerSpec) -> Callable:
+        """``fn(w, x)`` running this kernel for ``spec``, named
+        ``<layer kind>_<kernel>``: jitted, it is the XLA module
+        ``jit_<layer kind>_<kernel>`` in a profiler trace."""
+        def fn(w, x):
+            return self.execute(w, x, spec)
+
+        fn.__name__ = fn.__qualname__ = f"{spec.op_type}_{self.name}"
+        return fn
+
     def __repr__(self):
         return f"<Kernel {self.op_type}/{self.name}>"
 

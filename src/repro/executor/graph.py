@@ -22,11 +22,27 @@ an explicit DAG of typed tasks:
 ``simulate_graph`` maps a compiled graph back onto the scheduler's
 event-driven ``simulate`` — the plan's makespan model and the executor run
 the *same* structure, which the equivalence tests pin down.
+
+Spans: every timed step of a cold start is one ``OpTrace`` in its job's
+``traces``, opened with ``span``. An open span is also a
+``jax.profiler.TraceAnnotation`` named ``nnv12.<kind>``, so a running
+profiler puts it on the device trace's clock. Compiles (JAX's trace,
+lowering and backend-compile durations) become ``compile`` spans of the
+job whose thread ran them; garbage collections become ``gc`` spans on the
+absolute ``perf_counter`` clock (``collections_between``).
 """
 from __future__ import annotations
 
+import collections
+import contextlib
+import gc
+import threading
+import time
 from dataclasses import dataclass, field
-from typing import Callable, Dict, List, Optional, Sequence, Tuple
+from typing import Any, Callable, Deque, Dict, List, Optional, Sequence, \
+    Tuple
+
+from jax.profiler import TraceAnnotation
 
 from repro.core.scheduler import Plan, simulate
 
@@ -41,11 +57,155 @@ AFFINITIES = ("big", "little", "any")
 
 @dataclass
 class OpTrace:
+    """One span. ``start``/``end`` are seconds on its job's clock
+    (``t0``); a ``gc`` span not yet claimed by a job is on the absolute
+    ``perf_counter`` clock."""
     layer: str
     kind: str
     core: str
     start: float
     end: float
+    request: Optional[int] = None   # the job's ``seq``: one per cold start
+    parent: Optional[str] = None    # kind of the enclosing span
+    detail: str = ""                # a compile's stage and program; a
+    #                                 collection's generation and count
+
+
+# -- spans -------------------------------------------------------------------
+# per thread, the open spans: (job, kind, layer); kind None is a scope
+# that only names the job (``serving``)
+_tls = threading.local()
+# JAX's durations that make a compile span, and the stage each names
+_COMPILE_EVENTS = {
+    "/jax/core/compile/jaxpr_trace_duration": "trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower",
+    "/jax/core/compile/backend_compile_duration": "backend_compile",
+}
+# the process's latest collections (``gc`` spans, absolute clock)
+_gc_spans: Deque[OpTrace] = collections.deque(maxlen=4096)
+_gc_open: List[Tuple[float, TraceAnnotation]] = []
+_hooks_lock = threading.Lock()
+_hooks_on = False
+
+
+def _frames() -> list:
+    frames = getattr(_tls, "frames", None)
+    if frames is None:
+        frames = _tls.frames = []
+    return frames
+
+
+def _on_compile(event: str, secs: float, fun_name: str = "", **_) -> None:
+    stage = _COMPILE_EVENTS.get(event)
+    frames = getattr(_tls, "frames", None)
+    if stage is None or not frames or frames[-1][0] is None:
+        return
+    job, kind, layer = frames[-1]
+    end = time.perf_counter() - job.t0
+    job.traces.append(OpTrace(
+        layer, "compile", threading.current_thread().name, end - secs, end,
+        request=job.seq, parent=kind, detail=f"{stage} {fun_name}"))
+
+
+def _on_gc(phase: str, info: Dict[str, int]) -> None:
+    if phase == "start":
+        ann = TraceAnnotation("nnv12.gc", generation=info["generation"])
+        ann.__enter__()
+        _gc_open.append((time.perf_counter(), ann))
+    elif _gc_open:
+        start, ann = _gc_open.pop()
+        ann.__exit__(None, None, None)
+        _gc_spans.append(OpTrace(
+            "", "gc", threading.current_thread().name, start,
+            time.perf_counter(), detail=f"gen{info['generation']} "
+            f"collected {info['collected']}"))
+
+
+def _install_hooks() -> None:
+    """Once per process: JAX's compile durations and the collector's
+    callbacks feed spans."""
+    global _hooks_on
+    with _hooks_lock:
+        if _hooks_on:
+            return
+        import jax
+
+        jax.monitoring.register_event_duration_secs_listener(_on_compile)
+        gc.callbacks.append(_on_gc)
+        _hooks_on = True
+
+
+def collections_between(lo: float, hi: float) -> List[OpTrace]:
+    """The ``gc`` spans that overlap [lo, hi] (absolute clock)."""
+    return [s for s in list(_gc_spans) if s.end > lo and s.start < hi]
+
+
+@contextlib.contextmanager
+def serving(job: Any):
+    """Work this thread does inside belongs to ``job``: spans opened
+    without a job, and compiles, are recorded into it."""
+    frames = _frames()
+    frames.append((job, None, ""))
+    try:
+        yield
+    finally:
+        frames.pop()
+
+
+class span:
+    """One span of ``kind`` on the calling thread, as a context manager.
+
+    While open it is the profiler annotation ``nnv12.<kind>`` (``layer``
+    and ``request`` as metadata; with no profiler running the annotation
+    only checks for one) and the parent of spans and compiles that start
+    on this thread. On exit it appends an ``OpTrace`` to its job's
+    ``traces`` unless ``record`` is false. ``job`` (anything with
+    ``seq``, ``t0`` and ``traces``) defaults to the enclosing span's; with
+    none the span is only an annotation. ``start``/``end``/``seconds``
+    hold its absolute times once closed."""
+
+    __slots__ = ("kind", "layer", "detail", "job", "record", "parent",
+                 "start", "end", "_ann")
+
+    def __init__(self, kind: str, *, job: Any = None, layer: str = "",
+                 detail: str = "", record: bool = True):
+        self.kind, self.layer, self.detail = kind, layer, detail
+        self.job, self.record = job, record
+
+    def __enter__(self) -> "span":
+        if not _hooks_on:
+            _install_hooks()
+        frames = _frames()
+        top = frames[-1] if frames else (None, None, "")
+        if self.job is None:
+            self.job = top[0]
+        self.parent = top[1]
+        frames.append((self.job, self.kind, self.layer))
+        if self.job is None:
+            self._ann = TraceAnnotation(f"nnv12.{self.kind}",
+                                        layer=self.layer)
+        else:
+            self._ann = TraceAnnotation(f"nnv12.{self.kind}",
+                                        layer=self.layer,
+                                        request=self.job.seq)
+        self._ann.__enter__()
+        self.start = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc) -> None:
+        self.end = time.perf_counter()
+        self._ann.__exit__(None, None, None)
+        _frames().pop()
+        job = self.job
+        if self.record and job is not None:
+            job.traces.append(OpTrace(
+                self.layer, self.kind, threading.current_thread().name,
+                self.start - job.t0, self.end - job.t0, request=job.seq,
+                parent=self.parent, detail=self.detail))
+
+    @property
+    def seconds(self) -> float:
+        return self.end - self.start
 
 
 @dataclass

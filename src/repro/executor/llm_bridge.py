@@ -17,13 +17,15 @@ A cold LLM start becomes a first-token-optimal pipeline:
      token) into a KV slot and continues decoding.
 
 The result records the first-token timestamp against the job clock next to
-the prep/pack trace ends, so serving benchmarks can gate the headline
-claim: the first token is emitted before the last layer's (decode-path)
-prep completes, with prefill overlapping weight preparation layer-
-granularly.
+the prep/pack trace ends, and every span of the cold start on that clock
+(``ColdLLMResult.spans``): the task graph's, the compiles, the bridge's
+own steps (``first_token``, ``handoff.copy``, ``handoff.stack``,
+``handoff.replay``), the decode server's ticks and picks, and the garbage
+collections that ran meanwhile.
 """
 from __future__ import annotations
 
+import dataclasses
 import time
 from dataclasses import dataclass, field
 from typing import Any, Dict, List, Optional
@@ -36,7 +38,9 @@ from repro import quant
 from repro.configs.base import ArchConfig
 from repro.core.engine import ColdEngine
 from repro.core.pipeline import RunResult
-from repro.executor.graph import PREP_KINDS
+from repro.executor.graph import (
+    OpTrace, PREP_KINDS, collections_between, serving, span,
+)
 from repro.serving.server import BatchedServer, Request
 
 
@@ -57,15 +61,12 @@ class ColdLLMResult:
     logits: Optional[np.ndarray] = field(repr=False, default=None)
 
     @property
-    def first_token_before_last_prep(self) -> bool:
-        """Token 1 precedes the completion of the last layer's decode-path
-        prep. NOTE: this holds *by scheduling policy* (each pack depends on
-        its layer's execute, so packing can never delay the exec chain) —
-        it documents the policy, it is not evidence of overlap. The
-        overlap evidence is ``overlapped_layers`` (weight preps in flight
-        when the exec chain started) and ``overlapped_packs`` (decode-path
-        packs running concurrently with the exec chain)."""
-        return self.first_token_s < self.decode_prep_s
+    def spans(self) -> List[OpTrace]:
+        """Every span of this cold start, on the job clock and with the
+        job's ``request``: the run's traces, which the bridge extends with
+        its own spans, the decode server's and the collections inside the
+        call."""
+        return self.run.traces
 
 
 def _expand_quantized(w: Dict[str, Any],
@@ -143,16 +144,19 @@ def cold_start_llm(
             if graph.task(name, "stage") is not None:   # weighted layers only
                 graph.add(name, "pack", affinity="any", deps=(t.tid,), fn=fn)
 
+    called = time.perf_counter()
     if server is not None:
         ticket = server.cold_start(model_name, x, n_little=n_little,
                                    graph_hook=hook)
-        job, res = ticket.job, ticket.result()
+        pjob, res = ticket.job, ticket.result()
     else:
-        job = engine.submit_cold(x, n_little=n_little, graph_hook=hook)
-        res = job.result()
+        pjob = engine.submit_cold(x, n_little=n_little, graph_hook=hook)
+        res = pjob.result()
+    job = pjob.job                      # the pool's: seq, clock, traces
 
-    logits = np.asarray(res.output)                  # (1, S, V) float32
-    first_token = int(np.argmax(logits[0, -1]))
+    with span("first_token", job=job):
+        logits = np.asarray(res.output)              # (1, S, V) float32
+        first_token = int(np.argmax(logits[0, -1]))
     exec_traces = [t for t in res.traces if t.kind == "execute"]
     first_token_s = max(t.end for t in exec_traces)
     first_exec_start = min(t.start for t in exec_traces)
@@ -168,18 +172,20 @@ def cold_start_llm(
     # them over the transfer stream (the ``__packed__`` pseudo-layer),
     # flattened to "layer/key" so they cross the wire as plain arrays
     if server is not None and model_name is not None:
-        flat = {f"{lname}/{k}": np.asarray(v)
-                for lname, kv in packed.items() for k, v in kv.items()}
-        server.register_packed_state(model_name, flat)
+        with span("handoff.copy", job=job):
+            flat = {f"{lname}/{k}": np.asarray(v)
+                    for lname, kv in packed.items() for k, v in kv.items()}
+            server.register_packed_state(model_name, flat)
 
     # decode continuation: stack params, replay prompt + token 1 into a KV
     # slot, decode the rest greedily; the KV allocation draws from the
     # ColdServer's shared memory budget when one is serving this request
-    params = _pack_params(cfg, packed)
-    srv = BatchedServer(params, cfg, max_batch=1,
-                        max_len=int(prompt.size + max_new_tokens + 2),
-                        budget=(server.budget if server is not None
-                                else None))
+    with span("handoff.stack", job=job):
+        params = _pack_params(cfg, packed)
+        srv = BatchedServer(params, cfg, max_batch=1,
+                            max_len=int(prompt.size + max_new_tokens + 2),
+                            budget=(server.budget if server is not None
+                                    else None))
     tokens = [first_token]
     rows = [logits[0, -1]]
     if max_new_tokens > 1:
@@ -187,18 +193,23 @@ def cold_start_llm(
                       prompt=np.concatenate([prompt, [first_token]]),
                       max_new_tokens=max_new_tokens - 1,
                       out_logits=[] if keep_logits else None)
-        srv.submit(req)
-        srv.step()       # admit: replays the prompt into the KV slot
+        with span("handoff.replay", job=job):
+            srv.submit(req)
+            srv.admit()  # replays the prompt into the KV slot, picks token 2
         # decode-ready = params stacked + KV slot prefilled (NOT the full
         # decode drain — that scales with max_new_tokens)
         decode_ready_s = time.perf_counter() - job.t0
-        srv.run_until_drained()
+        with serving(job):          # its ticks' and picks' spans
+            srv.run_until_drained()
         assert req.done_s is not None, "decode did not drain"
         tokens += [int(tk) for tk in req.out_tokens]
         rows += req.out_logits or []
     else:
         decode_ready_s = time.perf_counter() - job.t0
     srv.close()     # return the KV reservation to the shared budget
+    for c in collections_between(called, time.perf_counter()):
+        job.traces.append(dataclasses.replace(
+            c, start=c.start - job.t0, end=c.end - job.t0, request=job.seq))
 
     return ColdLLMResult(
         tokens=tokens, first_token=first_token,

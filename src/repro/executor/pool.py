@@ -5,7 +5,9 @@ pipelined runtime used to spawn per run. Threads are created once (the pool
 grows on demand) and reused across runs *and models*: the steady cold-serving
 path performs zero thread creation. Jobs — compiled ``TaskGraph``s — are
 submitted concurrently; every task records an ``OpTrace`` against its own
-job's clock, so traces and benchmark breakdowns stay strictly per-run.
+job's clock, so traces and benchmark breakdowns stay strictly per-run, and
+runs inside a ``graph.span`` (``nnv12.<kind>`` in a profiler trace; the
+compiles it triggers become the job's ``compile`` spans).
 
 Scheduling rules (mirroring the plan simulator, §3.3):
 
@@ -55,7 +57,7 @@ import time
 from typing import Callable, Dict, List, Optional, Set, Tuple
 
 from repro.core.scheduler import pick_steal_donor
-from repro.executor.graph import OpTrace, PREP_KINDS, TaskGraph
+from repro.executor.graph import OpTrace, PREP_KINDS, TaskGraph, span
 from repro.faults import (
     DEFAULT_RETRY, DeadlineExceeded, JobTimeout, RetryPolicy, TransientFault,
     WorkerLost, classify,
@@ -707,7 +709,8 @@ class CorePool:
             if inj is not None:
                 inj.maybe_fault(f"task.{task.kind}",
                                 f"{job.name}:{task.layer}")
-            task.fn()
+            with span(task.kind, job=job, layer=task.layer, record=False):
+                task.fn()
         except BaseException as e:      # noqa: BLE001 — forwarded to caller
             err = classify(e, site=f"task.{task.kind}", layer=task.layer)
         te = time.perf_counter()
@@ -744,7 +747,8 @@ class CorePool:
                 fire_preps, finished = self._fail_job_locked(job, tid, err)
             else:
                 job.traces.append(OpTrace(task.layer, task.kind, core,
-                                          ts - job.t0, te - job.t0))
+                                          ts - job.t0, te - job.t0,
+                                          request=job.seq))
                 job._state[tid] = _DONE
                 job._done_count += 1
                 if task.kind in PREP_KINDS:

@@ -9,6 +9,11 @@ packed into the slot.
 
 Combined with the ColdEngine, a cold-started server overlaps model weight
 loading with the first prefill (examples/serve_cold.py).
+
+Each tick is a ``decode.step`` span and each token's pick a ``decode.pick``
+span (``executor.graph.span``), recorded into the cold start whose thread
+runs the server. A pick holds a ``decode.wait`` span: the host waiting for
+the device's token id; the rest of the pick is host work.
 """
 from __future__ import annotations
 
@@ -21,6 +26,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs.base import ArchConfig
+from repro.executor.graph import span
 from repro.models import transformer as T
 
 
@@ -89,25 +95,34 @@ class BatchedServer:
         # to the caller (a long-running server must not accumulate every
         # request it ever served)
         self.finished: List[Request] = []
-        self._decode = jax.jit(
-            lambda p, s, b, pos: T.decode_step(p, s, b, pos, cfg))
+        def decode_step(p, s, b, pos):
+            return T.decode_step(p, s, b, pos, cfg)
+
+        # named, so the program reads ``jit_decode_step`` in a trace
+        self._decode = jax.jit(decode_step)
         self._t0 = time.perf_counter()
         self._key = jax.random.PRNGKey(0)
 
     def _pick(self, req: Request, logits_row: jax.Array) -> int:
-        if req.out_logits is not None:
-            req.out_logits.append(np.asarray(logits_row, np.float32))
+        """Dispatch the key split and the sampling, then wait for the token
+        id (and the row, where the request keeps its logits) in a
+        ``decode.wait`` span."""
         self._key, sub = jax.random.split(self._key)
-        return int(sample_token(
-            logits_row, sub, temperature=req.temperature,
-            top_k=req.top_k, top_p=req.top_p))
+        tok = sample_token(logits_row, sub, temperature=req.temperature,
+                           top_k=req.top_k, top_p=req.top_p)
+        with span("decode.wait"):
+            if req.out_logits is not None:
+                req.out_logits.append(np.asarray(logits_row, np.float32))
+            return int(tok)
 
     # ------------------------------------------------------------------
     def submit(self, req: Request):
         req.submitted_s = time.perf_counter() - self._t0
         self.queue.append(req)
 
-    def _admit(self):
+    def admit(self):
+        """Move queued requests into free slots, replaying each prompt into
+        its KV slot and picking its first new token."""
         for slot in range(self.max_batch):
             if self.slot_req[slot] is None and self.queue:
                 req = self.queue.pop(0)
@@ -128,13 +143,14 @@ class BatchedServer:
                 self.params, self.state,
                 {"tokens": jnp.asarray(batch_tok)}, jnp.int32(self.pos[slot]))
             self.pos[slot] += 1
-        nxt = self._pick(req, logits[slot, 0])
+        with span("decode.pick"):
+            nxt = self._pick(req, logits[slot, 0])
         req.out_tokens.append(nxt)
         req.first_token_s = time.perf_counter() - self._t0
 
     def step(self) -> int:
         """One decode tick for all live slots. Returns #live slots."""
-        self._admit()
+        self.admit()
         live = [s for s in range(self.max_batch) if self.slot_req[s] is not None]
         if not live:
             return 0
@@ -145,13 +161,18 @@ class BatchedServer:
         # prefilled at different times decode with their own mask lengths
         # tracked in the cache ring; demo server keeps slots in lockstep)
         pos = int(max(self.pos[s] for s in live))
-        logits, self.state = self._decode(
-            self.params, self.state, {"tokens": jnp.asarray(batch_tok)},
-            jnp.int32(pos))
+        # the tick's span is its dispatch; the device's work ends inside
+        # the picks' ``decode.wait`` spans, whose host copies wait for it
+        with span("decode.step"):
+            logits, self.state = self._decode(
+                self.params, self.state,
+                {"tokens": jnp.asarray(batch_tok)}, jnp.int32(pos))
         for s in live:
             self.pos[s] = pos + 1
             req = self.slot_req[s]
-            req.out_tokens.append(self._pick(req, logits[s, 0]))
+            with span("decode.pick"):
+                tok = self._pick(req, logits[s, 0])
+            req.out_tokens.append(tok)
             if len(req.out_tokens) >= req.max_new_tokens:
                 req.done_s = time.perf_counter() - self._t0
                 self.finished.append(req)
