@@ -170,10 +170,11 @@ def cold_start_llm(
     # packed decode params are now "present" on this worker: register them
     # with the ColdServer so sibling workers' warm-state fetches can ride
     # them over the transfer stream (the ``__packed__`` pseudo-layer),
-    # flattened to "layer/key" so they cross the wire as plain arrays
+    # flattened to "layer/key". They stay on the device: a peer's fetch
+    # copies them to the host, never this request
     if server is not None and model_name is not None:
         with span("handoff.copy", job=job):
-            flat = {f"{lname}/{k}": np.asarray(v)
+            flat = {f"{lname}/{k}": v
                     for lname, kv in packed.items() for k, v in kv.items()}
             server.register_packed_state(model_name, flat)
 

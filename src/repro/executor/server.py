@@ -48,9 +48,12 @@ from repro.faults import DeadlineExceeded, ModelQuarantined
 def _weights_nbytes(weights: Optional[Dict[str, Any]]) -> int:
     total = 0
     for w in (weights or {}).values():
-        for v in w.values():
-            total += int(getattr(v, "nbytes", 0))
+        total += _arrays_nbytes(w)
     return total
+
+
+def _arrays_nbytes(arrays: Optional[Dict[str, Any]]) -> int:
+    return sum(int(getattr(v, "nbytes", 0)) for v in (arrays or {}).values())
 
 
 class MemoryBudget:
@@ -226,9 +229,12 @@ class ColdServer:
                       "peer_races_declined": 0, "peer_layers_fetched": 0,
                       "peer_bytes_fetched": 0, "peer_crc_failures": 0,
                       "peer_refusals": 0, "transfers_served": 0,
-                      "transfer_refusals": 0}
+                      "transfer_refusals": 0, "packed_host_copies": 0,
+                      "packed_host_bytes": 0}
         # packed decode params (LLM bridge) by model — servable over the
-        # warm-state channel under the reserved ``__packed__`` pseudo-layer
+        # warm-state channel under the reserved ``__packed__`` pseudo-layer,
+        # held as registered (device arrays stay on the device) until the
+        # model is evicted
         self._packed_state: Dict[str, Dict[str, Any]] = {}
         # peer link bandwidth EWMA, seeded by the first measured transfer;
         # feeds the same transfer_estimate the front door routes with
@@ -625,10 +631,21 @@ class ColdServer:
                     break
                 victim, nb = self._resident.popitem(last=False)
                 self._resident_weights.pop(victim, None)
+                packed = self._packed_state.pop(victim, None)
                 self.stats["evictions"] += 1
             self.budget.release(f"staged:{victim}", nb)
-            freed += nb
+            freed += nb + self._release_packed(victim, packed)
         return freed
+
+    def _release_packed(self, name: str,
+                        packed: Optional[Dict[str, Any]]) -> int:
+        """Return a popped packed state's charge to the budget, by its own
+        count (a registration racing the pop keeps its charge); the bytes
+        it freed."""
+        nb = _arrays_nbytes(packed)
+        if nb:
+            self.budget.release(f"packed:{name}", nb)
+        return nb
 
     @property
     def memory_budget_bytes(self) -> Optional[int]:
@@ -654,8 +671,10 @@ class ColdServer:
         with self._lock:
             self._resident_weights.pop(name, None)
             nb = self._resident.pop(name, None)
+            packed = self._packed_state.pop(name, None)
         if nb is not None:
             self.budget.release(f"staged:{name}", nb)
+        self._release_packed(name, packed)
         return nb is not None
 
     # -- warm-state transfer serving (docs/warm_transfer.md) -----------------
@@ -694,21 +713,37 @@ class ColdServer:
             return None, "memory pressure"
         return state, "ok"
 
+    def count_packed_host_copy(self, nbytes: int) -> None:
+        """A transfer copied one packed array to the host from the device
+        (``packed_host_copies``/``packed_host_bytes``)."""
+        with self._lock:
+            self.stats["packed_host_copies"] += 1
+            self.stats["packed_host_bytes"] += int(nbytes)
+
     def register_packed_state(self, name: str, params: Dict[str, Any]):
         """Packed decode-path params (the LLM bridge's ``pack`` output):
-        kept servable over the warm-state channel under the reserved
-        ``__packed__`` pseudo-layer, charged to the shared budget."""
-        flat = {k: np.asarray(v) for k, v in params.items()
+        kept as given (host or device arrays) and servable over the
+        warm-state channel under the reserved ``__packed__`` pseudo-layer
+        until the model is evicted, charged to the shared budget. Nothing
+        is kept for a model evicted before its packed state arrives: no
+        transfer could serve it, and no eviction would drop it."""
+        flat = {k: v for k, v in params.items()
                 if getattr(v, "nbytes", None) is not None}
         if not flat:
             return
-        nbytes = sum(int(v.nbytes) for v in flat.values())
-        with self._lock:
-            old = self._packed_state.pop(name, None)
-            self._packed_state[name] = flat
-        if old is not None:
-            self.budget.release(f"packed:{name}")
+        nbytes = _arrays_nbytes(flat)
+        # reserve OUTSIDE self._lock (the budget's evictors re-enter it);
+        # charges are released by count, so an eviction between here and
+        # the store below returns only what it popped
         self.budget.reserve(f"packed:{name}", nbytes)
+        with self._lock:
+            resident = name in self._resident
+            old = self._packed_state.pop(name, None)
+            if resident:
+                self._packed_state[name] = flat
+        self._release_packed(name, old)
+        if not resident:
+            self.budget.release(f"packed:{name}", nbytes)
 
     # -- warm-run batching (front-door worker coalescing) --------------------
     def warm_run_many(self, name: str, xs: Sequence[Any]

@@ -160,13 +160,16 @@ class WarmStateServer:
             wanted = [n for n in wanted if n in state]
             state = {n: state[n] for n in wanted}
         layers = [n for n, kv in state.items() if kv]
-        total = sum(int(np.asarray(a).nbytes)
-                    for kv in state.values() for a in kv.values())
+        # each array crosses to the host once, in its chunk's copy below
+        total = sum(int(a.nbytes) for kv in state.values()
+                    for a in kv.values())
         send_msg(sock, {"type": "accept", "model": model,
                         "layers": layers, "total_bytes": total})
         for layer in layers:
             for key, arr in state[layer].items():
                 a = np.asarray(arr)
+                if layer == PACKED_LAYER and a is not arr:
+                    self.server.count_packed_host_copy(a.nbytes)
                 data = a.tobytes()
                 crc = _crc(data)
                 if self.corrupt_chunks > 0:

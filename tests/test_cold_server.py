@@ -136,6 +136,88 @@ def test_cold_llm_first_token_before_last_layer_prep(tmp_path):
     assert res.tokens[0] == res.first_token
 
 
+@pytest.mark.parametrize("evict_by", ["evict", "budget"])
+def test_cold_llm_packed_state_stays_on_device_until_evicted(tmp_path,
+                                                            evict_by):
+    """The bridge registers the packed decode weights as the device arrays
+    they are (no host copy on the request path); eviction, by ``evict``
+    or by the LRU under a budget that holds one model, drops them and
+    their ``packed:`` charge."""
+    import jax
+
+    from repro.configs import get_config
+    from repro.core.llm_graph import tiny_llm_graph
+    from repro.executor.llm_bridge import cold_start_llm
+
+    cfg = get_config("smollm-360m").reduced(
+        num_layers=4, d_model=128, d_ff=256, num_heads=2, num_kv_heads=1,
+        head_dim=64, vocab_size=512)
+    graph, toks = tiny_llm_graph(4)
+    srv = ColdServer(tmp_path, n_little=2)
+    eng = srv.add_model("llm", graph)
+    srv.decide("llm", toks, n_little=2)
+    cold_start_llm(eng, cfg, toks[0], max_new_tokens=2, n_little=2,
+                   server=srv, model_name="llm")
+    packed = srv._packed_state["llm"]
+    assert packed and all(isinstance(v, jax.Array) for v in packed.values())
+    assert srv.stats["packed_host_copies"] == 0
+    assert srv.stats["packed_host_bytes"] == 0
+    assert srv.budget.used_by("packed:llm") == sum(
+        int(v.nbytes) for v in packed.values())
+    if evict_by == "evict":
+        assert srv.evict("llm")
+    else:
+        graph2, _ = tiny_llm_graph(4, seed=1)
+        srv.add_model("llm2", graph2)
+        srv.decide("llm2", toks, n_little=2)
+        srv.memory_budget_bytes = srv.budget.used() + 1
+        srv.cold_start("llm2", toks).result()
+        assert srv.resident_models() == ["llm2"]
+        assert srv.stats["evictions"] == 1
+        assert not srv.budget.over_budget()
+    assert "llm" not in srv._packed_state
+    assert srv.budget.used_by("packed:llm") == 0
+    assert "packed:llm" not in srv.budget.snapshot()["by_tag"]
+
+
+def test_cold_llm_evicted_before_registration_keeps_no_packed_state(
+        tmp_path):
+    """A model evicted after its cold start but before the bridge registers
+    its packed weights keeps neither the device arrays nor their charge;
+    a later registration of a resident model is charged in full."""
+    from repro.configs import get_config
+    from repro.core.llm_graph import tiny_llm_graph
+    from repro.executor.llm_bridge import cold_start_llm
+
+    cfg = get_config("smollm-360m").reduced(
+        num_layers=4, d_model=128, d_ff=256, num_heads=2, num_kv_heads=1,
+        head_dim=64, vocab_size=512)
+    graph, toks = tiny_llm_graph(4)
+    srv = ColdServer(tmp_path, n_little=2)
+    eng = srv.add_model("llm", graph)
+    srv.decide("llm", toks, n_little=2)
+    register = srv.register_packed_state
+    seen = {}
+
+    def evict_then_register(name, params):
+        assert srv.evict(name)
+        register(name, params)
+        seen.update(params)
+
+    srv.register_packed_state = evict_then_register
+    res = cold_start_llm(eng, cfg, toks[0], max_new_tokens=2, n_little=2,
+                         server=srv, model_name="llm")
+    assert res.tokens and seen
+    assert "llm" not in srv._packed_state
+    assert "packed:llm" not in srv.budget.snapshot()["by_tag"]
+    assert srv.budget.used() == 0
+    srv.cold_start("llm", toks).result()
+    register("llm", seen)
+    assert srv.budget.used_by("packed:llm") == sum(
+        int(v.nbytes) for v in seen.values())
+    assert srv.evict("llm") and srv.budget.used() == 0
+
+
 def test_batched_server_run_until_drained_returns_finished():
     """Regression: run_until_drained used to always return [] — it must
     return the requests that finished during the call."""

@@ -14,7 +14,7 @@ import repro.ioengine as iomod
 from repro.checkpoint import LayerStore
 from repro.faults import FaultInjector, ReadFault, RetryPolicy
 from repro.ioengine import (
-    IOEngine, PinnedBufferPool, StageEngine, available_backends,
+    IOEngine, POOL_BYTES, PinnedBufferPool, StageEngine, available_backends,
     get_io_engine, reset_io_engine, reset_stage_engine,
 )
 
@@ -142,6 +142,28 @@ def test_pool_overflow_allocs_beyond_budget_are_unpooled():
     assert pool.stats["overflow_allocs"] == 1
     big.release()
     assert pool.stats["retained_bytes"] <= 8192
+    pool.close()
+
+
+def test_pool_default_keeps_a_jobs_slabs_for_the_next():
+    """Under its default cap the pool keeps every slab it allocates: a
+    second job of the same shape reuses them all, and classes used one
+    after another each keep their slab instead of reallocating per job."""
+    pool = PinnedBufferPool(pin=False)
+    assert pool.max_bytes == POOL_BYTES
+    job = [pool.acquire(1 << 20) for _ in range(3)]
+    cls = job[0].capacity
+    for b in job:
+        b.release()
+    small = pool.acquire(1000)      # one class after another, as staging
+    small.release()
+    assert pool.stats["retained_bytes"] == 3 * cls + small.capacity
+    again = [pool.acquire(1 << 20) for _ in range(3)]
+    again.append(pool.acquire(1000))
+    assert pool.stats["reuses"] == 4 and pool.stats["allocs"] == 4
+    assert pool.stats["overflow_allocs"] == 0
+    for b in again:
+        b.release()
     pool.close()
 
 

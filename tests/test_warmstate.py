@@ -3,6 +3,7 @@ fallback, the fetch-vs-disk race (bit-identity + journaling), chaos at
 the fetch sites, memory-pressure refusal, and the abortable paced read
 that keeps a race-losing read from sleeping out the emulated disk."""
 import os
+import socket
 import tempfile
 import threading
 import time
@@ -210,6 +211,63 @@ def test_refusal_under_memory_pressure(donor):
         srv.budget.release("test:pressure")
     state, reason = srv.resident_state_for_transfer("mnet")
     assert reason == "ok" and state
+
+
+def test_packed_device_arrays_served_bit_identical(tmp_path):
+    """Packed params registered as device arrays cross to the host only
+    when a peer fetches them: bit-identical, dtype (bf16 too) and shape
+    kept, ``total_bytes`` their ``nbytes``, and each fetch counted once
+    per array in ``packed_host_copies``/``packed_host_bytes``."""
+    import jax
+    import jax.numpy as jnp
+
+    from repro.executor.frontdoor import recv_msg, send_msg
+
+    srv, x = _mk_server(tmp_path)
+    srv.cold_start("mnet", x).result()
+    rng = np.random.default_rng(7)
+    packed = {
+        "blk0/w": jnp.asarray(rng.standard_normal((64, 48)), jnp.bfloat16),
+        "blk0/norm": jnp.asarray(rng.standard_normal(48), jnp.float32),
+        "blk1/q": jnp.asarray(rng.integers(-128, 128, (16, 8)), jnp.int8),
+    }
+    srv.register_packed_state("mnet", packed)
+    assert all(isinstance(v, jax.Array)
+               for v in srv._packed_state["mnet"].values())
+    n_arrays = len(packed)
+    n_bytes = sum(int(v.nbytes) for v in packed.values())
+    resident, _ = srv.resident_state_for_transfer("mnet")
+    state_bytes = sum(int(a.nbytes) for kv in resident.values()
+                      for a in kv.values())
+    assert srv.stats["packed_host_copies"] == 0    # packed=False: none
+    warm = WarmStateServer(srv)
+    try:
+        # the accept frame's total_bytes, on the raw wire
+        with socket.create_connection((warm.host, warm.port),
+                                      timeout=10) as sock:
+            send_msg(sock, {"type": "fetch", "model": "mnet",
+                            "layers": None, "packed": True})
+            accept = recv_msg(sock)
+            assert accept["type"] == "accept"
+            assert accept["total_bytes"] == state_bytes + n_bytes
+            while recv_msg(sock)["type"] != "done":
+                pass
+        assert srv.stats["packed_host_copies"] == n_arrays
+        assert srv.stats["packed_host_bytes"] == n_bytes
+        pf = PeerFetcher("mnet", [(warm.host, warm.port)])
+        try:
+            got = pf.fetch_packed()
+        finally:
+            pf.close()
+    finally:
+        warm.close()
+    assert set(got) == set(packed)
+    for k, v in packed.items():
+        assert got[k].dtype == v.dtype and got[k].shape == v.shape
+        np.testing.assert_array_equal(got[k].view(np.uint8),
+                                      np.asarray(v).view(np.uint8))
+    assert srv.stats["packed_host_copies"] == 2 * n_arrays
+    assert srv.stats["packed_host_bytes"] == 2 * n_bytes
 
 
 # ---------------------------------------------------------------------------
