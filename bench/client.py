@@ -10,12 +10,12 @@ benchmark stands beside it where the tokens appear:
   prefill's logits from the job's result (``output``) to the host, picks
   the first token there, and only then reads the result's ``traces``:
   that first read is the first token's time (or, should the bridge never
-  read them, the time the result came back). The copy of the packed
-  decode weights to the host that the bridge makes next, for
-  ``register_packed_state``, belongs to the hand-off. Decode starts when
-  the decode server reserves its KV cache. The graph hook is wrapped to
-  keep the job's staged weights, so that their types can be read once
-  the request has ended.
+  read them, the time the result came back). What the bridge does next,
+  registering the packed decode weights, which stay on the device, for
+  warm-state transfer (``register_packed_state``), belongs to the
+  hand-off. Decode starts when the decode server reserves its KV cache.
+  The graph hook is wrapped to keep the job's staged weights, so that
+  their types can be read once the request has ended.
 * ``token_clock`` wraps ``BatchedServer._pick``, which returns each later
   token id as a host integer, and notes the time it returns.
 

@@ -47,7 +47,8 @@ def serve(model, mix, seed, requests, root, state, trace_to=None,
     import run
     import tracing
 
-    c = run.Cell(model, mix, seed, run.store_base(root), state, **engine_kw)
+    c = run.Cell(model, mix, seed, run.store_base(root), state, root,
+                 **engine_kw)
     c.set_up()
     kernels = sorted({ch.kernel for ch in c.engine.plan.choices})
     tracer = tracing.Tracer(c.base.parent / "control-trace") \
@@ -71,8 +72,8 @@ def readings(cell_name: str, seed: int, requests: int, trace_to=None,
 
     import compare
     import device
+    import modelcfg
     import run
-    import weights
 
     bench, cell, conf, model, mix = run.load_cell(cell_name, root)
     device.require(require, cell["chips"])
@@ -90,7 +91,7 @@ def readings(cell_name: str, seed: int, requests: int, trace_to=None,
 
     ref = run.load_module(root / "bench" / "refs" /
                           f"{model['reference']}.py")
-    params = weights.make(seed, model)
+    params = modelcfg.family(model, root).make(seed, model)
     rows, positions = compare.served_rows([r["prompt"] for r in reqs],
                                           [r["served"] for r in reqs])
     exact = np.asarray(ref.logits_at(params, model, rows, positions))

@@ -1,7 +1,7 @@
 """Kernels: the decode step's share of its roofline, in %: the least time
-``flops.py`` gives for its runs, each at its position, over their summed
-device time in the trace. Silent when the trace shows no such
-executable."""
+the family gives for its runs, each at its position (its ``decode_step``
+role), over their summed device time in the trace. Silent when the trace
+shows no such executable."""
 
 
 def read(rec):

@@ -1,6 +1,7 @@
 """LLM bridge: mean over the window's requests of the program's
-``handoff.copy`` span, the host copy of the packed decode weights that
-``register_packed_state`` takes (program span)."""
+``handoff.copy`` span, the registration of the packed decode weights for
+warm-state transfer by ``register_packed_state``; the weights stay on the
+device (program span)."""
 import program_spans
 
 

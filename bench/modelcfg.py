@@ -1,40 +1,43 @@
-"""A configuration file (``bench/configs/<name>.json``, the published
-``config.json`` keys as run) read into the sizes the benchmark uses."""
+"""A configuration file (``bench/configs/<name>.json``) read into the model
+dict the benchmark passes around, and the model family that knows its
+shapes.
+
+The file holds the published ``config.json`` keys as run, and the
+harness's own: ``family``, the name of ``bench/families/<family>.py``,
+which reads the published keys; ``reference``, the name of
+``bench/refs/<reference>.py``; and ``limits``, the numbers that decide
+``correct``. The model dict is the family's sizes with ``name``,
+``family``, ``dtype`` (the published ``torch_dtype``), ``reference`` and
+``limits``.
+"""
 from __future__ import annotations
 
+import importlib.util
 import json
 from pathlib import Path
 from typing import Any, Dict
 
+ROOT = Path(__file__).resolve().parent.parent
 
-def model(path: Path, name: str) -> Dict[str, Any]:
+
+def family(m: Dict[str, Any], root: Path = ROOT):
+    """The family module of model ``m``, from ``root``'s
+    ``bench/families``; a family without a file is an error."""
+    path = Path(root) / "bench" / "families" / f"{m['family']}.py"
+    if not path.is_file():
+        raise FileNotFoundError(f"model {m['name']!r} names family "
+                                f"{m['family']!r}, which has no file {path}")
+    spec = importlib.util.spec_from_file_location(f"family_{path.stem}",
+                                                  path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+def model(path: Path, name: str, root: Path = ROOT) -> Dict[str, Any]:
     c = json.loads(Path(path).read_text())
-    heads = c["num_attention_heads"]
-    return {
-        "name": name,
-        "layers": c["num_hidden_layers"],
-        "d_model": c["hidden_size"],
-        "d_ff": c["intermediate_size"],
-        "vocab": c["vocab_size"],
-        "heads": heads,
-        "kv_heads": c["num_key_value_heads"],
-        "head_dim": c.get("head_dim") or c["hidden_size"] // heads,
-        "tied": c["tie_word_embeddings"],
-        "rope_theta": float(c["rope_theta"]),
-        "norm_eps": float(c["rms_norm_eps"]),
-        "dtype": c["torch_dtype"],
-        "reference": c["reference"],
-        "limits": c.get("limits", {}),
-    }
-
-
-def arch_config(m: Dict[str, Any]):
-    """The program's ``ArchConfig`` for ``m``: a dense decoder."""
-    from repro.configs.base import ArchConfig
-
-    return ArchConfig(
-        name=m["name"], family="dense", num_layers=m["layers"],
-        d_model=m["d_model"], d_ff=m["d_ff"], vocab_size=m["vocab"],
-        num_heads=m["heads"], num_kv_heads=m["kv_heads"],
-        head_dim=m["head_dim"], rope_theta=m["rope_theta"],
-        norm_eps=m["norm_eps"], tie_embeddings=m["tied"], dtype=m["dtype"])
+    m = {"name": name, "family": c["family"]}
+    m.update(family(m, root).model(c))
+    m.update(dtype=c["torch_dtype"], reference=c["reference"],
+             limits=c.get("limits", {}))
+    return m

@@ -7,9 +7,11 @@ A cell (``BENCHMARK.json``'s ``workloads``) names a model configuration
 (``bench/configs/<config>.json``) and a traffic mix
 (``bench/traffic/<mix>.json``). One run:
 
-1. set-up (timed as ``setup_s``): weights from ``--seed`` on the device;
-   the program's cold graph (``build_llm_graph``), a ``ColdServer`` whose
-   model store lies on a disk filesystem outside the checkout, its offline
+1. set-up (timed as ``setup_s``): weights from ``--seed`` on the device,
+   drawn by the configuration's model family
+   (``bench/families/<family>.py``); the program's cold graph
+   (``build_llm_graph``), a ``ColdServer`` whose model store lies on a
+   disk filesystem outside the checkout, its offline
    ``decide()`` at the mix's prompt shape, and the mix's
    ``warmup_requests`` whole cold requests: the first warms every shape
    the window uses, the rest the process's host memory;
@@ -112,7 +114,7 @@ def load_cell(name: str, root: Path = ROOT):
                          f"(have {sorted(cells)})")
     cell = cells[name]
     conf = next(c for c in bench["configs"] if c["name"] == cell["config"])
-    model = modelcfg.model(root / conf["file"], conf["name"])
+    model = modelcfg.model(root / conf["file"], conf["name"], root)
     mix = json.loads(
         (root / "bench" / "traffic" / f"{cell['traffic']}.json").read_text())
     if mix["page_cache"] != "warm":
@@ -157,9 +159,9 @@ class Cell:
     """The system under test, set up for one cell, and its requests."""
 
     def __init__(self, model: dict, mix: dict, seed: int, base: Path,
-                 state: Path, **engine_kw):
+                 state: Path, root: Path = ROOT, **engine_kw):
         self.model, self.mix, self.seed, self.base = model, mix, seed, base
-        self.state = state
+        self.state, self.root = state, root
         self.engine_kw = engine_kw
         self.phases = {}
 
@@ -171,14 +173,14 @@ class Cell:
         import jax
 
         import modelcfg
-        import weights
         from repro.core.llm_graph import build_llm_graph
         from repro.executor.server import ColdServer
 
-        self.cfg = modelcfg.arch_config(self.model)
+        family = modelcfg.family(self.model, self.root)
+        self.cfg = family.arch_config(self.model)
         shutil.rmtree(self.base, ignore_errors=True)
         t = time.perf_counter()
-        params = weights.make(self.seed, self.model)
+        params = family.make(self.seed, self.model)
         jax.block_until_ready(params)
         self.phase("weights", t)
         t = time.perf_counter()
@@ -279,7 +281,7 @@ def widest_gap(model: dict, mix: dict, seed: int, reqs: list,
     import numpy as np
 
     import compare
-    import weights
+    import modelcfg
 
     n = mix["compare_requests"]
     rng = np.random.default_rng([seed, 3])
@@ -287,7 +289,7 @@ def widest_gap(model: dict, mix: dict, seed: int, reqs: list,
                              replace=False).tolist())
     sample = [reqs[i] for i in pick]
     ref = load_module(root / "bench" / "refs" / f"{model['reference']}.py")
-    params = weights.make(seed, model)
+    params = modelcfg.family(model, root).make(seed, model)
     gap = compare.widest_gap(ref, params, model,
                              [r["prompt"] for r in sample],
                              [r["served"] for r in sample])
@@ -320,9 +322,11 @@ def run(cell_name: str, seed: int, seconds: float, trace: bool,
     import compiles
     import device
     import filesystem
+    import modelcfg
     import tracing
 
     bench, cell, conf, model, mix = load_cell(cell_name, root)
+    family = modelcfg.family(model, root)
     devs = device.require(require, cell["chips"])
     peak = device.peak(devs[0].device_kind, root / "bench" / "peaks.json")
     say(f"cell {cell_name}: {conf['name']} x {cell['traffic']}, seed {seed}, "
@@ -333,7 +337,7 @@ def run(cell_name: str, seed: int, seconds: float, trace: bool,
     fs, mnt = filesystem.filesystem_of(base.parent)
     say(f"model store at {base} on {fs} ({mnt}); page cache "
         f"{mix['page_cache']}")
-    c = Cell(model, mix, seed, base, root / STATE_DIR)
+    c = Cell(model, mix, seed, base, root / STATE_DIR, root)
     c.set_up()
     setup_s = time.perf_counter() - T_START
     say(f"set-up {setup_s:.4f}s; {counter.compiled} backend compiles "
@@ -387,13 +391,15 @@ def run(cell_name: str, seed: int, seconds: float, trace: bool,
     reduced = None
     if xplane is not None:
         t = time.perf_counter()
-        reduced = tracing.reduce(*tracing.load(xplane), model, mix, peak)
+        reduced = tracing.reduce(*tracing.load(xplane),
+                                 family.roles(model, mix, peak))
         tracer.close()
         say(f"trace of {min(i, TRACED_REQUESTS)} requests read in "
             f"{time.perf_counter() - t:.2f}s: busy {reduced['busy_s']:.4f}s "
             f"of {reduced['window_s']:.4f}s; {reduced['kernels']}")
-    rec = {"model": model, "mix": mix, "requests": reqs, "setup_s": setup_s,
-           "compiled_in_window": in_window, "trace": reduced, "peak": peak}
+    rec = {"model": model, "family": family, "mix": mix, "requests": reqs,
+           "setup_s": setup_s, "compiled_in_window": in_window,
+           "trace": reduced, "peak": peak}
     metrics = {}
     for name, unit in metrics_for(bench, cell, trace):
         v = load_module(root / "bench" / "metrics" / f"{name}.py").read(rec)

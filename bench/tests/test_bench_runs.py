@@ -33,9 +33,11 @@ TINY = {
 SEED = 2**31 + 77
 
 
-def _add_config(root: Path, name: str, traffic: str = "cold_host"):
-    conf = dict(TINY[name], source="test", reference="dense_decoder",
-                rms_norm_eps=1e-5, torch_dtype="bfloat16")
+def _add_config(root: Path, name: str, traffic: str = "cold_host",
+                family: str = "dense"):
+    conf = dict(TINY[name], source="test", family=family,
+                reference="dense_decoder", rms_norm_eps=1e-5,
+                torch_dtype="bfloat16")
     (root / "bench" / "configs" / f"{name}.json").write_text(
         json.dumps(conf))
     bench = json.loads((root / "BENCHMARK.json").read_text())
@@ -65,10 +67,10 @@ def checkout(tmp_path, monkeypatch):
     return root
 
 
-def _run(root, cell, seconds=1.0):
+def _run(root, cell, seconds=1.0, trace=False):
     import run
 
-    return run.run(cell, SEED, seconds, False, root=root, require="cpu")
+    return run.run(cell, SEED, seconds, trace, root=root, require="cpu")
 
 
 @pytest.mark.parametrize("where", ["repo", "bench_files_only"])
@@ -174,19 +176,19 @@ def test_nemo_shapes_match_the_reference_through_the_cold_graph(tmp_path):
     import compare
     import modelcfg
     import run
-    import weights
     from repro.core.llm_graph import build_llm_graph
     from repro.executor.llm_bridge import cold_start_llm
     from repro.executor.server import ColdServer
 
     path = tmp_path / "tiny-nemo.json"
     path.write_text(json.dumps(dict(
-        TINY["tiny-nemo"], reference="dense_decoder", rms_norm_eps=1e-5,
-        torch_dtype="bfloat16")))
+        TINY["tiny-nemo"], family="dense", reference="dense_decoder",
+        rms_norm_eps=1e-5, torch_dtype="bfloat16")))
     m = modelcfg.model(path, "tiny-nemo")
-    cfg = modelcfg.arch_config(m)
+    family = modelcfg.family(m)
+    cfg = family.arch_config(m)
     assert cfg.num_heads * cfg.head_dim != cfg.d_model
-    params = weights.make(SEED, m)
+    params = family.make(SEED, m)
     graph, _ = build_llm_graph(cfg, params)
     server = ColdServer(tmp_path / "server", n_little=2)
     eng = server.add_model("m", graph)

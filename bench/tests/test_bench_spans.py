@@ -158,8 +158,8 @@ def test_reduce_finds_the_kernels_in_either_trace(name):
     devices, host = tracing.load_json(DATA / name)
     model = modelcfg.model(BENCH / "configs" / "smollm-360m.json", "smollm")
     peak = {"bf16_flops_per_s": 197e12, "hbm_bytes_per_s": 819e9}
-    out = tracing.reduce(devices, host, model,
-                         {"prompt_tokens": 128, "new_tokens": 32}, peak)
+    out = tracing.reduce(devices, host, modelcfg.family(model).roles(
+        model, {"prompt_tokens": 128, "new_tokens": 32}, peak))
     prefix, tblock_s, step_s = FIXTURES[name]
     tb, step = out["kernels"]["tblock"], out["kernels"]["decode_step"]
     assert tb["name"].startswith(prefix) and tb["runs"] == 32

@@ -20,8 +20,10 @@ import tracing  # noqa: E402
 
 DATA = BENCH / "tests" / "data" / "trace_smollm_1req.json"
 MODEL = modelcfg.model(BENCH / "configs" / "smollm-360m.json", "smollm")
+DENSE = modelcfg.family(MODEL)
 MIX = {"prompt_tokens": 128, "new_tokens": 32}
 PEAK = {"bf16_flops_per_s": 197e12, "hbm_bytes_per_s": 819e9}
+ROLES = DENSE.roles(MODEL, MIX, PEAK)
 
 
 @pytest.fixture(scope="module")
@@ -39,7 +41,7 @@ def test_busy_and_idle(trace):
         if e > s:
             busy += e - s
             end = e
-    out = tracing.reduce(devices, host, MODEL, MIX, PEAK)
+    out = tracing.reduce(devices, host, ROLES)
     assert out["busy_s"] == pytest.approx(busy / 1e9, rel=1e-12)
     assert out["busy_s"] == pytest.approx(0.246398, abs=1e-6)
     assert out["window_s"] == pytest.approx(3.947778, abs=1e-6)
@@ -47,7 +49,7 @@ def test_busy_and_idle(trace):
 
 def test_executables_found_and_timed(trace):
     devices, host = trace
-    out = tracing.reduce(devices, host, MODEL, MIX, PEAK)
+    out = tracing.reduce(devices, host, ROLES)
     tb, step = out["kernels"]["tblock"], out["kernels"]["decode_step"]
     runs = {}
     for name, s, e in devices[0]:
@@ -59,16 +61,16 @@ def test_executables_found_and_timed(trace):
     assert step["runs"] == 159
     assert step["device_s"] == pytest.approx(0.238732, abs=1e-6)
     assert tb["least_s"] == pytest.approx(
-        32 * flops.least_time(flops.tblock(MODEL, 128), PEAK))
+        32 * flops.least_time(DENSE.tblock(MODEL, 128), PEAK))
     assert step["least_s"] == pytest.approx(sum(
-        flops.least_time(flops.decode_step(MODEL, p), PEAK)
+        flops.least_time(DENSE.decode_step(MODEL, p), PEAK)
         for p in range(159)))
     assert tb["least_s"] < tb["device_s"]
     assert step["least_s"] < step["device_s"]
 
 
 def test_breakdown_lists_at_most_ten_each(trace):
-    out = tracing.reduce(*trace, MODEL, MIX, PEAK)
+    out = tracing.reduce(*trace, ROLES)
     ops, gaps = out["breakdown"]["device_ops"], out["breakdown"]["idle_gaps"]
     assert 0 < len(ops) <= 10 and 0 < len(gaps) <= 10
     assert ops[0][0].startswith("decode_step ")
@@ -79,6 +81,6 @@ def test_breakdown_lists_at_most_ten_each(trace):
 def test_a_trace_without_the_kernels_leaves_them_out(trace):
     devices, host = trace
     host = [h for h in host if h[0] == "bench.window"]
-    out = tracing.reduce(devices, host, MODEL, MIX, PEAK)
+    out = tracing.reduce(devices, host, ROLES)
     assert out["kernels"] == {}
     assert out["busy_s"] > 0

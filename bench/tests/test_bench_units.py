@@ -1,9 +1,13 @@
-"""Unit tests of the benchmark's yardstick: operation counts, spans, the
-store's filesystem, peaks, the client's clock and the metric readers."""
+"""Unit tests of the benchmark's yardstick: operation counts, seeded
+weights, spans, the store's filesystem, peaks, the client's clock and the
+metric readers."""
+import hashlib
 import importlib.util
+import json
 import sys
 from pathlib import Path
 
+import numpy as np
 import pytest
 
 BENCH = Path(__file__).resolve().parents[1]
@@ -15,6 +19,7 @@ import filesystem  # noqa: E402
 import spans  # noqa: E402
 
 SMOLLM = modelcfg.model(BENCH / "configs" / "smollm-360m.json", "smollm")
+DENSE = modelcfg.family(SMOLLM)
 V5E = {"bf16_flops_per_s": 197e12, "hbm_bytes_per_s": 819e9}
 
 
@@ -28,11 +33,52 @@ def reader(name):
 
 def test_smollm_config_is_the_published_model():
     assert SMOLLM == {
-        "name": "smollm", "layers": 32, "d_model": 960, "d_ff": 2560,
-        "vocab": 49152, "heads": 15, "kv_heads": 5, "head_dim": 64,
+        "name": "smollm", "family": "dense", "layers": 32, "d_model": 960,
+        "d_ff": 2560, "vocab": 49152, "heads": 15, "kv_heads": 5,
+        "head_dim": 64,
         "tied": True, "rope_theta": 10000.0, "norm_eps": 1e-5,
         "dtype": "bfloat16", "reference": "dense_decoder",
         "limits": SMOLLM["limits"]}
+
+
+# two tiny dense configurations, one with an untied output head, and the
+# SHA-256 of the weights the harness drew for each from seed 2**31 + 77
+# before the dense family had a module of its own
+TINY_DENSE = {
+    "tiny-nemo": ({"hidden_size": 160, "intermediate_size": 448,
+                   "num_hidden_layers": 2, "num_attention_heads": 4,
+                   "num_key_value_heads": 1, "head_dim": 32,
+                   "vocab_size": 512, "tie_word_embeddings": False,
+                   "rope_theta": 1e6},
+                  "aa5b0085a746a477ba11f3c4ceb5a619"
+                  "3f50c15d96b196add5df92e25ddb9c61"),
+    "tiny-tied": ({"hidden_size": 96, "intermediate_size": 256,
+                   "num_hidden_layers": 3, "num_attention_heads": 3,
+                   "num_key_value_heads": 1, "vocab_size": 384,
+                   "tie_word_embeddings": True, "rope_theta": 1e4},
+                  "4886a6f2c578029b2d9892f23459e608"
+                  "eef0e83b5c9de7431e25335cfd35ee42"),
+}
+
+
+@pytest.mark.parametrize("name", list(TINY_DENSE))
+def test_dense_family_draws_the_same_weights(name, tmp_path):
+    import jax
+
+    keys, want = TINY_DENSE[name]
+    path = tmp_path / f"{name}.json"
+    path.write_text(json.dumps(dict(
+        keys, family="dense", reference="dense_decoder", rms_norm_eps=1e-5,
+        torch_dtype="bfloat16")))
+    m = modelcfg.model(path, name)
+    h = hashlib.sha256()
+    leaves = jax.tree_util.tree_flatten_with_path(DENSE.make(2**31 + 77, m))
+    for where, leaf in leaves[0]:
+        a = np.asarray(leaf)
+        h.update(f"{jax.tree_util.keystr(where)} {a.dtype} {a.shape};"
+                 .encode())
+        h.update(a.tobytes())
+    assert h.hexdigest() == want
 
 
 def test_tblock_matches_hand_count():
@@ -41,17 +87,17 @@ def test_tblock_matches_hand_count():
     weights = 9_830_400
     # 2 per multiply-add for 128 rows; causal attention: 15 heads x 64 dims
     # x (1 + 2 + ... + 128) = 8256 pairs, twice (scores, values)
-    assert flops.tblock(SMOLLM, 128)["flops"] == \
+    assert DENSE.tblock(SMOLLM, 128)["flops"] == \
         2 * 128 * weights + 2 * 2 * 15 * 64 * 8256 == 2_548_285_440
     # bf16 weights and the two norm gains, the (128, 960) bf16 in and out
-    assert flops.tblock(SMOLLM, 128)["bytes"] == \
+    assert DENSE.tblock(SMOLLM, 128)["bytes"] == \
         2 * (weights + 2 * 960) + 2 * 2 * 128 * 960 == 20_156_160
 
 
 def test_decode_step_matches_hand_count():
     per_layer = 2 * 9_830_400 + 2 * 2 * 15 * 64 * 11     # attends to 0..10
     head = 2 * 960 * 49152
-    assert flops.decode_step(SMOLLM, 10)["flops"] == \
+    assert DENSE.decode_step(SMOLLM, 10)["flops"] == \
         32 * per_layer + head == 724_869_120
     # weights, norm gains and keys+values of 11 positions x 5 heads x 64;
     # the output projection's weights and gain, its f32 logits, one
@@ -59,14 +105,14 @@ def test_decode_step_matches_hand_count():
     kv = 2 * 2 * 11 * 5 * 64
     per_layer_b = 2 * (9_830_400 + 2 * 960) + kv
     head_b = 2 * (960 * 49152 + 960) + 4 * 49152
-    assert flops.decode_step(SMOLLM, 10)["bytes"] == \
+    assert DENSE.decode_step(SMOLLM, 10)["bytes"] == \
         32 * per_layer_b + head_b + 2 * 960 == 724_291_328
 
 
 def test_least_time_takes_the_binding_bound():
-    work = flops.tblock(SMOLLM, 128)
+    work = DENSE.tblock(SMOLLM, 128)
     assert flops.least_time(work, V5E) == pytest.approx(20_156_160 / 819e9)
-    big = flops.tblock(SMOLLM, 4096)
+    big = DENSE.tblock(SMOLLM, 4096)
     assert flops.least_time(big, V5E) == pytest.approx(big["flops"] / 197e12)
 
 
@@ -86,7 +132,7 @@ def test_store_filesystem_is_named(tmp_path):
 
 def test_first_token_is_timed_before_the_bridge_copies_its_weights():
     """The bridge picks the first token from the result's ``output``, then
-    reads its ``traces``, then copies its packed weights to the host for
+    reads its ``traces``, then registers its packed weights with
     ``register_packed_state``: the clock stops at the second step."""
     import time
 

@@ -10,12 +10,10 @@ TraceMe events on) around the requests it is given. ``reduce`` reads the
 * the phases of each request are the host annotations ``bench.prefill``
   and ``bench.decode`` (``client.py``); device events are attributed to the
   phase whose interval holds their start;
-* the program gives its executables no names of their own (each is a
-  ``jit__lambda`` with a fingerprint), so they are told apart by what the
-  algorithm fixes: the tblock executable is the one that runs once per
-  layer in every prefill, the decode step one that runs at least once per
-  decoded token in every decode phase; of several such, the one with the
-  most device time.
+* the kernels are the roles that the model's family declares (``roles``
+  in ``bench/families/<family>.py``): each role's executable is, of those
+  whose name and number of runs its rule accepts in every phase of its
+  kind, the one with the most device time.
 
 Events carry nanosecond times on one clock for host and device planes.
 """
@@ -26,13 +24,25 @@ import os
 import shutil
 from collections import defaultdict
 from pathlib import Path
-from typing import Dict, List, Optional, Tuple
+from typing import Callable, Dict, List, NamedTuple, Optional, Tuple
 
-import flops
 from spans import clip, merge
 
 WINDOW, PREFILL, DECODE = "bench.window", "bench.prefill", "bench.decode"
 TOP = 10
+
+
+class Role(NamedTuple):
+    """A kernel that a model family declares, reported under ``name`` in
+    ``reduce``'s ``kernels``."""
+    name: str
+    # "prefill" or "decode": the phase of each traced request it runs in
+    phase: str
+    # whether an executable of this name that ran this many times in one
+    # phase can be it
+    want: Callable[[str, int], bool]
+    # the least seconds of its runs, from their number in each phase
+    least_s: Callable[[List[int]], float]
 
 
 class Tracer:
@@ -121,16 +131,16 @@ def _runs_by_phase(mods, phases) -> List[Dict[str, List[float]]]:
 
 
 def _pick(per_phase, want) -> Optional[str]:
-    """Of the executables whose number of runs ``want`` accepts in every
-    phase, the one with the most device time."""
+    """Of the executables whose name and number of runs ``want`` accepts
+    in every phase, the one with the most device time."""
     names = set.intersection(*[set(p) for p in per_phase]) if per_phase \
         else set()
-    ok = [n for n in names if all(want(len(p[n])) for p in per_phase)]
+    ok = [n for n in names if all(want(n, len(p[n])) for p in per_phase)]
     return max(ok, key=lambda n: sum(sum(p[n]) for p in per_phase)) \
         if ok else None
 
 
-def reduce(devices, host, model: dict, mix: dict, peak: dict) -> dict:
+def reduce(devices, host, roles: List[Role]) -> dict:
     win = _spans(host, WINDOW)
     if not win or not devices:
         raise RuntimeError("the trace holds no traced window or no device")
@@ -140,27 +150,18 @@ def reduce(devices, host, model: dict, mix: dict, peak: dict) -> dict:
         busy.append(sum(e - s for s, e in merge(
             clip([(s, e) for _, s, e in mods], w0, w1))) / 1e9)
     mods = devices[0]
-    prefill = _runs_by_phase(mods, _spans(host, PREFILL))
-    decode = _runs_by_phase(mods, _spans(host, DECODE))
-    S, N = mix["prompt_tokens"], mix["new_tokens"]
-
-    tblock = _pick(prefill, lambda n: n == model["layers"])
-    step = _pick(decode, lambda n: n >= N - 2)
+    phases = {"prefill": _runs_by_phase(mods, _spans(host, PREFILL)),
+              "decode": _runs_by_phase(mods, _spans(host, DECODE))}
     out = {"busy_s": sum(busy) / len(busy), "window_s": (w1 - w0) / 1e9,
            "kernels": {}}
-    if tblock:
-        times = [t for p in prefill for t in p[tblock]]
-        least = len(times) * flops.least_time(flops.tblock(model, S), peak)
-        out["kernels"]["tblock"] = {"name": tblock, "runs": len(times),
-                                    "device_s": sum(times),
-                                    "least_s": least}
-    if step:
-        times = [t for p in decode for t in p[step]]
-        least = sum(flops.decode_least_time(model, range(len(p[step])), peak)
-                    for p in decode)
-        out["kernels"]["decode_step"] = {"name": step, "runs": len(times),
-                                         "device_s": sum(times),
-                                         "least_s": least}
+    for role in roles:
+        per_phase = phases[role.phase]
+        name = _pick(per_phase, role.want)
+        if name:
+            times = [t for p in per_phase for t in p[name]]
+            out["kernels"][role.name] = {
+                "name": name, "runs": len(times), "device_s": sum(times),
+                "least_s": role.least_s([len(p[name]) for p in per_phase])}
     out["breakdown"] = _breakdown(mods, host, (w0, w1), out["kernels"])
     return out
 
