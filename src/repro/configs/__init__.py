@@ -17,6 +17,7 @@ _ARCH_MODULES = {
     "gemma2-27b": "gemma2_27b",
     "internvl2-76b": "internvl2_76b",
     "qwen3-32b": "qwen3_32b",
+    "mellum2-12b-a2.5b": "mellum2_12b_a2_5b",
 }
 
 # the paper's own evaluation models (CNN chains for the cold engine) are in
@@ -35,6 +36,7 @@ ASSIGNED_ARCHS = [
     "gemma2-27b",
     "internvl2-76b",
     "qwen3-32b",
+    "mellum2-12b-a2.5b",
 ]
 
 

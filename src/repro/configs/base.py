@@ -10,6 +10,24 @@ import dataclasses
 from dataclasses import dataclass, field
 from typing import Optional, Tuple
 
+# fields added after shape-class keys were first stored: they enter a
+# layer's key (``registry.shape_class_key``) only where they are set, so
+# the keys of models that leave them unset stay as they were
+_KEYED_WHEN_SET = {"keyed_when_set": True}
+
+
+@dataclass(frozen=True)
+class Yarn:
+    """YaRN's RoPE scaling (as Hugging Face ``transformers`` computes it):
+    frequencies between the two correction dimensions blend from
+    extrapolated to interpolated (divided by ``factor``), and cos and sin
+    are multiplied by ``attention_factor``."""
+    factor: float
+    original_max_positions: int
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    attention_factor: float = 1.0
+
 
 @dataclass(frozen=True)
 class ArchConfig:
@@ -30,11 +48,24 @@ class ArchConfig:
     sliding_window: Optional[int] = None    # window for 'local' layers
     local_global_pattern: bool = False      # gemma2: alternate local/global
     rope_theta: float = 10_000.0
+    # per-layer attention kinds, repeated over the depth: "local" (a
+    # sliding window of ``sliding_window``) or "attn" (full), each layer's
+    # kind carried as data through the scanned stack; empty: one kind for
+    # every layer, ``sliding_window`` applying to all where set
+    layer_pattern: Tuple[str, ...] = field(default=(), metadata=_KEYED_WHEN_SET)
+    # YaRN scaling of the full ("attn") layers' RoPE; local layers keep
+    # plain RoPE
+    rope_yarn: Optional[Yarn] = field(default=None, metadata=_KEYED_WHEN_SET)
 
     # MoE
-    num_experts: int = 0
+    num_experts: int = 0        # experts the router spans
     top_k: int = 0
     moe_capacity_factor: float = 2.0
+    # the experts this device holds, of the ``num_experts`` the router
+    # spans (expert parallelism): the layer routes over all of them and
+    # adds its own experts' share, dropless. Empty: the capacity-factor
+    # layer over every expert
+    held_experts: Tuple[int, ...] = field(default=(), metadata=_KEYED_WHEN_SET)
 
     # SSM (Mamba2 / SSD)
     ssm_state: int = 0
@@ -78,10 +109,19 @@ class ArchConfig:
     def ssm_heads(self) -> int:
         return self.ssm_inner // self.ssm_head_dim
 
+    @property
+    def experts_here(self) -> int:
+        """Experts whose weights this device holds."""
+        return len(self.held_experts) or self.num_experts
+
     def layer_kinds(self) -> Tuple[str, ...]:
         """Per-layer mixer kind: 'attn' | 'local' | 'mamba'."""
         if self.family in ("ssm", "hybrid"):
             return ("mamba",) * self.num_layers
+        if self.layer_pattern:
+            n = len(self.layer_pattern)
+            return tuple(self.layer_pattern[i % n]
+                         for i in range(self.num_layers))
         if self.local_global_pattern:
             # gemma2: even layers local (sliding window), odd layers global
             return tuple(
@@ -104,7 +144,7 @@ class ArchConfig:
                 per_attn += 2 * self.head_dim
         per_mlp = 3 * d * ff if ff else 0
         if self.is_moe:
-            per_mlp = self.num_experts * 3 * d * ff + d * self.num_experts
+            per_mlp = self.experts_here * 3 * d * ff + d * self.num_experts
         per_mamba = 0
         if self.family in ("ssm", "hybrid"):
             di, G, N, H = self.ssm_inner, 1, self.ssm_state, self.ssm_heads
@@ -139,7 +179,8 @@ class ArchConfig:
         if not self.is_moe:
             return self.param_count()
         d, ff = self.d_model, self.d_ff
-        inactive = self.num_layers * (self.num_experts - self.top_k) * 3 * d * ff
+        inactive = self.num_layers * max(
+            self.experts_here - self.top_k, 0) * 3 * d * ff
         return self.param_count() - inactive
 
     def reduced(self, **overrides) -> "ArchConfig":
@@ -162,6 +203,9 @@ class ArchConfig:
             num_prefix_embeds=min(self.num_prefix_embeds, 8),
         )
         small.update(overrides)
+        if self.held_experts and "held_experts" not in overrides:
+            small["held_experts"] = tuple(
+                e for e in self.held_experts if e < small["num_experts"])
         return dataclasses.replace(self, **small)
 
     def with_sliding_window(self, window: int = 4096) -> "ArchConfig":

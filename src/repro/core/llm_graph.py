@@ -12,7 +12,13 @@ from disk, so the paper's three knobs apply to LLM serving directly:
       approaches warm prefill latency.
 
 The graph is embed -> L× tblock -> final_norm+lm_head, all chain-shaped (the
-engine's dependency model); residual adds live inside each block unit.
+engine's dependency model); residual adds live inside each block unit. A
+sparse-expert model (``family == "moe"``) has one ``moeblock`` unit per
+block instead: its attention, norms, router and the experts held on this
+device (``held_experts``), whose weight names start with ``expert_``.
+Its spec's config carries the layer's attention kind (``local`` or
+``attn``), so blocks of different kinds fall into different shape classes
+and compile apart.
 """
 from __future__ import annotations
 
@@ -28,11 +34,14 @@ from repro.core.engine import LayerDef
 from repro.core.registry import (
     Kernel, KERNEL_REGISTRY, LOSSY_KERNELS, LayerSpec,
 )
+from repro.core.staging import EXPERT_PREFIX
 from repro.models import layers as L
+from repro.models import moe as MOE
 
 
-def _block_forward(w: Dict[str, jnp.ndarray], x: jnp.ndarray, cfg: ArchConfig,
-                   dtype) -> jnp.ndarray:
+def _attn_residual(w, x, cfg: ArchConfig, dtype, window, yarn=None):
+    """A block's first half, ``x + attn(norm(x))``, and its weights cast
+    to ``dtype``."""
     B, S, _ = x.shape
     positions = jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32), (B, S))
     wd = {k: v.astype(dtype) for k, v in w.items()}
@@ -40,13 +49,38 @@ def _block_forward(w: Dict[str, jnp.ndarray], x: jnp.ndarray, cfg: ArchConfig,
     if cfg.qk_norm:
         p["q_norm"], p["k_norm"] = wd["q_norm"], wd["k_norm"]
     h = L.rms_norm(x, wd["ln1"], cfg.norm_eps)
-    attn, _ = L.attn_apply_seq(p, h, cfg, positions,
-                               window=cfg.sliding_window)
-    x = x + attn
+    attn, _ = L.attn_apply_seq(p, h, cfg, positions, window=window,
+                               yarn=yarn)
+    return x + attn, wd
+
+
+def _block_forward(w: Dict[str, jnp.ndarray], x: jnp.ndarray, cfg: ArchConfig,
+                   dtype) -> jnp.ndarray:
+    x, wd = _attn_residual(w, x, cfg, dtype, cfg.sliding_window)
     h = L.rms_norm(x, wd["ln2"], cfg.norm_eps)
     mlp = L.mlp_apply(
         {"w_gate": wd["w_gate"], "w_up": wd["w_up"], "w_down": wd["w_down"]}, h)
     return x + mlp
+
+
+def _moe_block_forward(w: Dict[str, jnp.ndarray], x: jnp.ndarray,
+                       spec: LayerSpec, dtype) -> jnp.ndarray:
+    """A sparse-expert block of the kind its spec names: a local layer
+    attends within ``sliding_window`` with plain RoPE, a full layer to
+    every earlier position with ``rope_yarn``'s RoPE; then this device's
+    held experts' share of the expert layer."""
+    cfg = spec.config["cfg"]
+    local = spec.config["kind"] == "local"
+    # the residual stream in the model's dtype from the first block on
+    # (the embedding's rows are bf16 values)
+    x = x.astype(dtype)
+    x, wd = _attn_residual(w, x, cfg, dtype,
+                           cfg.sliding_window if local else None,
+                           None if local else cfg.rope_yarn)
+    h = L.rms_norm(x, wd["ln2"], cfg.norm_eps)
+    experts = {k: wd[EXPERT_PREFIX + k] for k in ("w_gate", "w_up", "w_down")}
+    experts["router"] = wd["router"]
+    return x + MOE.held_experts_apply(experts, h, cfg)
 
 
 class TBlockF32Direct(Kernel):
@@ -71,6 +105,24 @@ class TBlockBf16(Kernel):
 
     def execute(self, w, x, spec):
         return _block_forward(w, x, spec.config["cfg"], jnp.bfloat16)
+
+
+class MoeBlockF32Direct(Kernel):
+    """A sparse-expert block from its f32 master weights, cast at execute
+    to the model's dtype (bf16 as deployed)."""
+    name = "f32_direct"
+    op_type = "moeblock"
+
+    def execute(self, w, x, spec):
+        return _moe_block_forward(w, x, spec,
+                                  jnp.dtype(spec.config["cfg"].dtype))
+
+
+class MoeBlockBf16(MoeBlockF32Direct):
+    """The block transformed to bf16, its deployed precision: half the
+    cached bytes, the same execution as ``f32_direct``."""
+    name = "bf16_cast"
+    transform = TBlockBf16.transform
 
 
 class EmbedDirect(Kernel):
@@ -203,6 +255,8 @@ class HeadInt4(HeadInt8):
 
 
 KERNEL_REGISTRY.setdefault("tblock", [TBlockF32Direct(), TBlockBf16()])
+# no lossy variant: a sparse-expert block stages in its deployed precision
+KERNEL_REGISTRY.setdefault("moeblock", [MoeBlockF32Direct(), MoeBlockBf16()])
 KERNEL_REGISTRY.setdefault("embed", [EmbedDirect(), EmbedBf16()])
 KERNEL_REGISTRY.setdefault("lmhead", [HeadDirect(), HeadBf16()])
 # quantized variants are lossy: eligible only under the engine's allow_lossy
@@ -220,23 +274,52 @@ def _layer_names(cfg: ArchConfig) -> List[str]:
             + ["lm_head"])
 
 
+def _specs(cfg: ArchConfig, shapes: Dict[str, Dict[str, tuple]]
+           ) -> List[LayerSpec]:
+    """The cold graph's layer specs from each layer's weight shapes."""
+    kinds = cfg.layer_kinds()
+    out = []
+    for name in _layer_names(cfg):
+        if name in _OP_TYPES:
+            out.append(LayerSpec(name, _OP_TYPES[name], {"cfg": cfg},
+                                 shapes[name]))
+        elif cfg.family == "moe":
+            out.append(LayerSpec(name, "moeblock",
+                                 {"cfg": cfg, "kind": kinds[int(name[5:])]},
+                                 shapes[name]))
+        else:
+            out.append(LayerSpec(name, "tblock", {"cfg": cfg}, shapes[name]))
+    return out
+
+
+def _block_keys(cfg: ArchConfig) -> List[Tuple[Tuple[str, ...], str]]:
+    """(path in the T-format block params, unit weight name) of a block."""
+    keys = ([(("ln1",), "ln1"), (("ln2",), "ln2")]
+            + [(("attn", k), k) for k in ("wq", "wk", "wv", "wo")])
+    if cfg.family == "moe":
+        keys += [(("moe", "router"), "router")]
+        keys += [(("moe", k), EXPERT_PREFIX + k)
+                 for k in ("w_gate", "w_up", "w_down")]
+    else:
+        keys += [(("mlp", k), k) for k in ("w_gate", "w_up", "w_down")]
+    if cfg.qk_norm:
+        keys += [(("attn", "q_norm"), "q_norm"), (("attn", "k_norm"), "k_norm")]
+    return keys
+
+
 def _layer_weights(cfg: ArchConfig, params) -> Dict[str, Dict[str, jax.Array]]:
     """{layer: {weight: array}} of the cold graph, cut from T-format params
     (traceable, so ``jax.eval_shape`` gives the shapes at no cost)."""
     blocks = params["blocks"]
     out = {"embed": {"embed": params["embed"]}}
-    keys = ([("ln1",), ("ln2",)]
-            + [("attn", k) for k in ("wq", "wk", "wv", "wo")]
-            + [("mlp", k) for k in ("w_gate", "w_up", "w_down")])
-    if cfg.qk_norm:
-        keys += [("attn", "q_norm"), ("attn", "k_norm")]
+    keys = _block_keys(cfg)
     for i in range(cfg.num_layers):
         bw = {}
-        for path in keys:
+        for path, name in keys:
             leaf = blocks
             for p in path:
                 leaf = leaf[p]
-            bw[path[-1]] = leaf[i]
+            bw[name] = leaf[i]
         out[f"block{i:03d}"] = bw
     head_w = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
     out["lm_head"] = {"w": head_w, "final_norm": params["final_norm"]}
@@ -250,26 +333,25 @@ def build_llm_graph_specs(cfg: ArchConfig) -> List[LayerSpec]:
 
     shapes = jax.eval_shape(lambda: _layer_weights(
         cfg, T.init_params(jax.random.PRNGKey(0), cfg)))
-    return [LayerSpec(n, _OP_TYPES.get(n, "tblock"), {"cfg": cfg},
-                      {k: tuple(v.shape) for k, v in shapes[n].items()})
-            for n in _layer_names(cfg)]
+    return _specs(cfg, {n: {k: tuple(v.shape) for k, v in ws.items()}
+                        for n, ws in shapes.items()})
 
 
 def build_llm_graph(cfg: ArchConfig, params) -> Tuple[List[LayerDef], np.ndarray]:
-    """Convert dense-family transformer params (from T.init_params) into an
-    engine graph + an example token batch. Raw storage is f32 (the master
-    checkpoint); execution is bf16 (the deployed precision)."""
-    assert cfg.family in ("dense",), "cold-LLM graph demo targets dense archs"
+    """Convert dense- or moe-family transformer params (from
+    T.init_params) into an engine graph + an example token batch. Raw
+    storage is f32 (the master checkpoint); execution is bf16 (the
+    deployed precision)."""
+    assert cfg.family in ("dense", "moe"), \
+        "the cold-LLM graph takes dense and sparse-expert decoders"
     layers = _layer_weights(cfg, params)
-    defs: List[LayerDef] = []
-    for name in _layer_names(cfg):
-        w = {k: np.asarray(jnp.asarray(v, jnp.float32))
-             for k, v in layers[name].items()}
-        defs.append(LayerDef(
-            spec=LayerSpec(name, _OP_TYPES.get(name, "tblock"), {"cfg": cfg},
-                           {k: tuple(v.shape) for k, v in w.items()}),
-            weights=w))
-    return defs, example_tokens(cfg.vocab_size)
+    weights = {name: {k: np.asarray(jnp.asarray(v, jnp.float32))
+                      for k, v in layers[name].items()}
+               for name in _layer_names(cfg)}
+    specs = _specs(cfg, {n: {k: tuple(v.shape) for k, v in w.items()}
+                         for n, w in weights.items()})
+    return ([LayerDef(spec=s, weights=weights[s.name]) for s in specs],
+            example_tokens(cfg.vocab_size))
 
 
 def example_tokens(vocab_size: int, length: int = 64) -> np.ndarray:

@@ -32,7 +32,9 @@ import jax.numpy as jnp
 
 from repro.core.registry import Kernel, LayerSpec
 from repro.core.scheduler import Plan
-from repro.core.staging import stage_weights
+from repro.core.staging import (
+    COUNTERS, EXPERT_PREFIX, expert_bytes, stage_weights,
+)
 from repro.executor.graph import OpTrace, compile_plan
 from repro.executor.pool import CorePool, Job, get_core_pool
 from repro.faults import TransientFault
@@ -395,6 +397,15 @@ class PipelineRuntime:
             deferred_stage_affinity="any" if self.prefetch else "big",
             fetch_layers=fetch_layers,
         )
+        # a layer with a kind of its own names it, with its op type, in
+        # its read, stage and execute spans
+        for task in graph.tasks:
+            cfg = self.specs[task.layer].config
+            if "kind" in cfg and task.kind in ("read", "stage", "execute"):
+                task.detail = f"{self.specs[task.layer].op_type} {cfg['kind']}"
+        if any(k.startswith(EXPERT_PREFIX) for n in self.order
+               for k in self.specs[n].weight_shapes):
+            COUNTERS["expert_stage_jobs"] += 1
         # race bookkeeping: the winner cancels the loser by tid.  jobref is
         # a late-bound cell — task fns can start before ``pool.submit``
         # returns the Job; a miss in that window just means both sides run
@@ -456,6 +467,7 @@ class PipelineRuntime:
                     w = self.stage_engine.stage(src)
                 else:
                     w = self._device_put(src)
+                COUNTERS["expert_stage_bytes"] += expert_bytes(w)
                 with lock:
                     won = name not in weights
                     weights[name] = w

@@ -67,7 +67,12 @@ def _canon(v: Any) -> Any:
     if isinstance(v, dict):
         return [[str(k), _canon(v[k])] for k in sorted(v, key=str)]
     if dataclasses.is_dataclass(v) and not isinstance(v, type):
-        return [type(v).__name__, _canon(dataclasses.asdict(v))]
+        d = dataclasses.asdict(v)
+        for f in dataclasses.fields(v):
+            if (f.metadata.get("keyed_when_set")
+                    and getattr(v, f.name) == f.default):
+                del d[f.name]
+        return [type(v).__name__, _canon(d)]
     if isinstance(v, np.dtype):
         return str(v)
     return repr(v)

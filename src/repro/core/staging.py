@@ -13,13 +13,29 @@ cost, and execute runs against device-resident buffers that can never
 touch the disk. Heap arrays produced by kernel transforms pass straight
 through. The profiler uses the same helper, so measured ``stage_s`` is
 the cost the runtime actually pays.
+
+``COUNTERS`` are the process's staging counters, which the cold path's
+``stage`` ops feed: ``expert_stage_bytes``, the bytes of held-expert
+weights (a unit's weights named ``EXPERT_PREFIX...``) staged, and
+``expert_stage_jobs``, the cold starts whose graph holds such weights.
 """
 from __future__ import annotations
 
+import collections
 from typing import Any, Dict
 
 import jax
 import numpy as np
+
+#: the weight names of a sparse-expert unit's held experts start with this
+EXPERT_PREFIX = "expert_"
+COUNTERS: collections.Counter = collections.Counter()
+
+
+def expert_bytes(w: Dict[str, Any]) -> int:
+    """Bytes of the held-expert weights among ``w``."""
+    return sum(int(v.nbytes) for k, v in w.items()
+               if k.startswith(EXPERT_PREFIX))
 
 
 def stage_weights(w: Dict[str, Any]) -> Dict[str, Any]:

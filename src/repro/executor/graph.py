@@ -68,7 +68,8 @@ class OpTrace:
     request: Optional[int] = None   # the job's ``seq``: one per cold start
     parent: Optional[str] = None    # kind of the enclosing span
     detail: str = ""                # a compile's stage and program; a
-    #                                 collection's generation and count
+    #                                 collection's generation and count; a
+    #                                 layer kind's unit's op type and kind
 
 
 # -- spans -------------------------------------------------------------------
@@ -181,13 +182,12 @@ class span:
             self.job = top[0]
         self.parent = top[1]
         frames.append((self.job, self.kind, self.layer))
-        if self.job is None:
-            self._ann = TraceAnnotation(f"nnv12.{self.kind}",
-                                        layer=self.layer)
-        else:
-            self._ann = TraceAnnotation(f"nnv12.{self.kind}",
-                                        layer=self.layer,
-                                        request=self.job.seq)
+        meta = {"layer": self.layer}
+        if self.job is not None:
+            meta["request"] = self.job.seq
+        if self.detail:
+            meta["detail"] = self.detail
+        self._ann = TraceAnnotation(f"nnv12.{self.kind}", **meta)
         self._ann.__enter__()
         self.start = time.perf_counter()
         return self
@@ -221,6 +221,7 @@ class Task:
     fn: Optional[Callable[[], None]] = None
     deadline_s: Optional[float] = None  # per-task deadline; None = inherit
                                         # the job-level default (pool watchdog)
+    detail: str = ""                # its span's detail
     depth: int = 1                  # read tasks: planned I/O queue depth —
                                     # how many lane successors to submit
                                     # alongside this read (Plan.read_depth)

@@ -37,6 +37,7 @@ import numpy as np
 from repro import quant
 from repro.configs.base import ArchConfig
 from repro.core.engine import ColdEngine
+from repro.core.staging import EXPERT_PREFIX
 from repro.core.pipeline import RunResult
 from repro.executor.graph import (
     OpTrace, PREP_KINDS, collections_between, serving, span,
@@ -86,16 +87,23 @@ def _expand_quantized(w: Dict[str, Any],
 
 
 def _pack_params(cfg: ArchConfig, packed: Dict[str, Dict[str, Any]]):
-    """Stack per-layer packed weights into the T-format decode pytree."""
+    """Stack per-layer packed weights into the T-format decode pytree: a
+    ``moeblock``'s router and held experts under ``moe`` (the decode step
+    takes the layers' kinds from the config)."""
     blocks = []
     for i in range(cfg.num_layers):
         w = packed[f"block{i:03d}"]
         attn = {k: w[k] for k in ("wq", "wk", "wv", "wo")}
         if cfg.qk_norm:
             attn["q_norm"], attn["k_norm"] = w["q_norm"], w["k_norm"]
-        blocks.append({"ln1": w["ln1"], "ln2": w["ln2"], "attn": attn,
-                       "mlp": {k: w[k]
-                               for k in ("w_gate", "w_up", "w_down")}})
+        block = {"ln1": w["ln1"], "ln2": w["ln2"], "attn": attn}
+        if cfg.family == "moe":
+            block["moe"] = {"router": w["router"]}
+            block["moe"].update({k: w[EXPERT_PREFIX + k]
+                                 for k in ("w_gate", "w_up", "w_down")})
+        else:
+            block["mlp"] = {k: w[k] for k in ("w_gate", "w_up", "w_down")}
+        blocks.append(block)
     params: Dict[str, Any] = {
         "embed": packed["embed"]["embed"],
         "final_norm": packed["lm_head"]["final_norm"],

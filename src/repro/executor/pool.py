@@ -709,7 +709,8 @@ class CorePool:
             if inj is not None:
                 inj.maybe_fault(f"task.{task.kind}",
                                 f"{job.name}:{task.layer}")
-            with span(task.kind, job=job, layer=task.layer, record=False):
+            with span(task.kind, job=job, layer=task.layer,
+                      detail=task.detail, record=False):
                 task.fn()
         except BaseException as e:      # noqa: BLE001 — forwarded to caller
             err = classify(e, site=f"task.{task.kind}", layer=task.layer)
@@ -748,7 +749,8 @@ class CorePool:
             else:
                 job.traces.append(OpTrace(task.layer, task.kind, core,
                                           ts - job.t0, te - job.t0,
-                                          request=job.seq))
+                                          request=job.seq,
+                                          detail=task.detail))
                 job._state[tid] = _DONE
                 job._done_count += 1
                 if task.kind in PREP_KINDS:

@@ -10,6 +10,7 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 NEG_INF = -2.0e38  # large-negative float that survives bf16/f32 casts
 
@@ -28,19 +29,57 @@ def rms_norm(x: jax.Array, scale: jax.Array, eps: float = 1e-6) -> jax.Array:
 # ---------------------------------------------------------------------------
 # rotary embeddings
 # ---------------------------------------------------------------------------
-def rope(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
+def yarn_inv_freq(head_dim: int, theta: float, yarn) -> np.ndarray:
+    """YaRN's inverse frequencies (head_dim/2,), as Hugging Face
+    ``transformers`` computes them (``_compute_yarn_parameters``): below
+    the correction dimension of ``beta_fast`` rotations the RoPE
+    frequencies are kept, above that of ``beta_slow`` divided by
+    ``factor``, and blended linearly in between."""
+    def correction_dim(rotations):
+        return (head_dim * math.log(
+            yarn.original_max_positions / (rotations * 2 * math.pi))
+            / (2 * math.log(theta)))
+
+    low = max(math.floor(correction_dim(yarn.beta_fast)), 0)
+    high = min(math.ceil(correction_dim(yarn.beta_slow)), head_dim - 1)
+    if low == high:
+        high += 0.001
+    ramp = np.clip((np.arange(head_dim // 2, dtype=np.float32) - low)
+                   / (high - low), 0.0, 1.0)
+    extrapolated = 1.0 / theta ** (
+        np.arange(0, head_dim, 2, dtype=np.float32) / head_dim)
+    return (extrapolated * (1.0 - ramp)
+            + extrapolated / yarn.factor * ramp).astype(np.float32)
+
+
+def rope(x: jax.Array, positions: jax.Array, theta: float, yarn=None,
+         use_yarn=None) -> jax.Array:
     """Apply rotary embedding.
 
-    x: (..., S, H, D) ; positions: broadcastable to (..., S)
+    x: (..., S, H, D) ; positions: broadcastable to (..., S). With
+    ``yarn`` (a ``configs.base.Yarn``) the frequencies are YaRN's and cos
+    and sin are scaled by its attention factor; ``use_yarn``, a traced
+    bool, then picks YaRN or plain RoPE (a layer kind carried as data).
     """
     d = x.shape[-1]
     half = d // 2
     freq = jnp.exp(
         -math.log(theta) * jnp.arange(0, half, dtype=jnp.float32) / half
     )
+    scale = None
+    if yarn is not None:
+        yfreq = jnp.asarray(yarn_inv_freq(d, theta, yarn))
+        scale = jnp.float32(yarn.attention_factor)
+        if use_yarn is None:
+            freq = yfreq
+        else:
+            freq = jnp.where(use_yarn, yfreq, freq)
+            scale = jnp.where(use_yarn, scale, jnp.float32(1.0))
     angle = positions.astype(jnp.float32)[..., None] * freq  # (..., S, half)
     cos = jnp.cos(angle)[..., None, :]  # (..., S, 1, half)
     sin = jnp.sin(angle)[..., None, :]
+    if scale is not None:
+        cos, sin = cos * scale, sin * scale
     x1, x2 = x[..., :half].astype(jnp.float32), x[..., half:].astype(jnp.float32)
     out = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
     return out.astype(x.dtype)
@@ -198,7 +237,8 @@ def mlp_apply(p: dict, x: jax.Array) -> jax.Array:
 # ---------------------------------------------------------------------------
 # attention apply (sequence mode: train / prefill)
 # ---------------------------------------------------------------------------
-def attn_qkv(p: dict, x: jax.Array, cfg, positions: jax.Array):
+def attn_qkv(p: dict, x: jax.Array, cfg, positions: jax.Array, *,
+             yarn=None, use_yarn=None):
     B, S, _ = x.shape
     H, KV, hd = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
     q = (x @ p["wq"]).reshape(B, S, H, hd)
@@ -207,8 +247,8 @@ def attn_qkv(p: dict, x: jax.Array, cfg, positions: jax.Array):
     if cfg.qk_norm:
         q = rms_norm(q, p["q_norm"], cfg.norm_eps)
         k = rms_norm(k, p["k_norm"], cfg.norm_eps)
-    q = rope(q, positions, cfg.rope_theta)
-    k = rope(k, positions, cfg.rope_theta)
+    q = rope(q, positions, cfg.rope_theta, yarn, use_yarn)
+    k = rope(k, positions, cfg.rope_theta, yarn, use_yarn)
     return q, k, v
 
 
@@ -221,11 +261,15 @@ def attn_apply_seq(
     window: Optional[int] = None,
     chunked: bool = False,
     chunk: int = 1024,
+    yarn=None,
+    use_yarn=None,
 ):
     """Self-attention over a full sequence. Returns (out, (k, v)) so callers
-    can keep the KV for cache initialisation (prefill)."""
+    can keep the KV for cache initialisation (prefill). ``window`` may be
+    traced (a layer kind carried as data); ``yarn``/``use_yarn`` as in
+    ``rope``."""
     B, S, _ = x.shape
-    q, k, v = attn_qkv(p, x, cfg, positions)
+    q, k, v = attn_qkv(p, x, cfg, positions, yarn=yarn, use_yarn=use_yarn)
 
     out = _maybe_seqpar_attention(q, k, v, positions, cfg, window, chunked, chunk)
     if out is None:
@@ -423,9 +467,12 @@ def attn_decode_step(
     window: Optional[int] = None,
     k_scale: Optional[jax.Array] = None,  # (B, W, KV) f32 when int8 cache
     v_scale: Optional[jax.Array] = None,
+    yarn=None,
+    use_yarn=None,
 ):
     """One decode step. The cache is a ring buffer of length W; for full
-    attention W == max_len and no entry is ever overwritten. Returns
+    attention W == max_len and no entry is ever overwritten. ``window``
+    may be traced; ``yarn``/``use_yarn`` as in ``rope``. Returns
     (out, (cache_k, cache_v[, k_scale, v_scale]))."""
     B, _, _ = x.shape
     H, KV, hd = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
@@ -438,8 +485,8 @@ def attn_decode_step(
         q = rms_norm(q, p["q_norm"], cfg.norm_eps)
         k = rms_norm(k, p["k_norm"], cfg.norm_eps)
     posb = jnp.full((B, 1), pos, jnp.int32)
-    q = rope(q, posb, cfg.rope_theta)
-    k = rope(k, posb, cfg.rope_theta)
+    q = rope(q, posb, cfg.rope_theta, yarn, use_yarn)
+    k = rope(k, posb, cfg.rope_theta, yarn, use_yarn)
     slot = jnp.mod(pos, W)
     if quant:
         kq, ks = _quantize_kv(k)

@@ -1,10 +1,15 @@
-"""Mixture-of-Experts layer: top-k router + sort-based grouped matmul.
+"""Mixture-of-Experts layers: a top-k router and the experts' SwiGLU.
 
-Dropless dispatch: tokens are sorted by assigned expert and pushed through
-``jax.lax.ragged_dot`` (the lax grouped-matmul primitive — the natural TPU
-mapping of MegaBlocks-style grouped GEMM). Compute is proportional to the
-*active* expert parameters only; no capacity-factor token dropping, no giant
-one-hot dispatch tensors.
+Two layers:
+
+* ``held_experts_apply``, for a config that names the experts held here
+  (expert parallelism, one device's share): every held expert runs over
+  every token, weighted by its routing weight, so it is dropless at any
+  token count. The cold path's ``moeblock`` units and decode take it.
+* the capacity layer (``_local_moe``): tokens sorted by assigned expert,
+  each expert a block of ``C`` rows (``cfg.moe_capacity_factor`` sizes
+  ``C``); tokens beyond an expert's capacity are dropped. Compute is
+  proportional to the *active* expert parameters.
 
 Baseline sharding (see DESIGN.md §5): expert weights are sharded over the
 ``model`` mesh axis along the per-expert ffn dimension (expert tensor
@@ -57,16 +62,47 @@ grouped_matmul.defvjp(_gm_fwd, _gm_bwd)
 
 
 def moe_init(key, cfg) -> dict:
-    d, ff, E = cfg.d_model, cfg.d_ff, cfg.num_experts
+    """The router over all ``num_experts`` and the weights of the experts
+    held here (``cfg.experts_here``), stacked in ``held_experts`` order."""
+    d, ff, E, H = cfg.d_model, cfg.d_ff, cfg.num_experts, cfg.experts_here
     dt = jnp.dtype(cfg.dtype)
     ks = jax.random.split(key, 4)
     scale = 1.0 / math.sqrt(d)
     return {
         "router": _dense_init(ks[0], (d, E), jnp.float32, scale),
-        "w_gate": (jax.random.normal(ks[1], (E, d, ff), jnp.float32) * scale).astype(dt),
-        "w_up": (jax.random.normal(ks[2], (E, d, ff), jnp.float32) * scale).astype(dt),
-        "w_down": (jax.random.normal(ks[3], (E, ff, d), jnp.float32) / math.sqrt(ff)).astype(dt),
+        "w_gate": (jax.random.normal(ks[1], (H, d, ff), jnp.float32) * scale).astype(dt),
+        "w_up": (jax.random.normal(ks[2], (H, d, ff), jnp.float32) * scale).astype(dt),
+        "w_down": (jax.random.normal(ks[3], (H, ff, d), jnp.float32) / math.sqrt(ff)).astype(dt),
     }
+
+
+def held_experts_apply(p: dict, x: jax.Array, cfg) -> jax.Array:
+    """The share of an expert layer that the experts held here give:
+    x (..., d) -> (..., d).
+
+    The router spans all ``cfg.num_experts``: softmax, the top ``top_k``,
+    renormalised to sum to one. Each held expert
+    (``cfg.held_experts``; every expert where empty) adds
+    ``p_e * SwiGLU_e(x)`` for the tokens that picked it and nothing for
+    the others; what the experts held elsewhere give is left out (expert
+    parallelism without its exchange). Every held expert runs over every
+    token, weighted by its routing weight (zero where not picked), so no
+    token is ever dropped, whatever the routing. ``p`` holds ``router``
+    (d, E) and ``w_gate``/``w_up`` (H, d, ff), ``w_down`` (H, ff, d)."""
+    lead, d = x.shape[:-1], x.shape[-1]
+    xf = x.reshape(-1, d)
+    logits = jnp.dot(xf, p["router"].astype(xf.dtype),
+                     preferred_element_type=jnp.float32)      # (T, E)
+    top_p, top_e = jax.lax.top_k(jax.nn.softmax(logits, axis=-1), cfg.top_k)
+    top_p = top_p / jnp.sum(top_p, axis=-1, keepdims=True)
+    held = jnp.asarray(cfg.held_experts or range(cfg.num_experts), jnp.int32)
+    gate = jnp.sum(jnp.where(top_e[:, :, None] == held, top_p[:, :, None],
+                             0.0), axis=1)                     # (T, H)
+    h = (jax.nn.silu(jnp.einsum("td,hdf->thf", xf, p["w_gate"]))
+         * jnp.einsum("td,hdf->thf", xf, p["w_up"]))
+    h = h * gate[:, :, None].astype(h.dtype)
+    y = jnp.einsum("thf,hfd->td", h, p["w_down"])
+    return y.reshape(*lead, d)
 
 
 def _local_moe(xf: jax.Array, router, w_gate, w_up, w_down, cfg):
@@ -237,12 +273,16 @@ def moe_apply(p: dict, x: jax.Array, cfg) -> tuple[jax.Array, jax.Array]:
     """x: (B, S, d) -> (y, aux_loss).
 
     aux_loss is the standard switch-transformer load-balance loss
-    (mean_e frac_tokens_e * mean_router_prob_e * E).
+    (mean_e frac_tokens_e * mean_router_prob_e * E). A config that names
+    the experts held here (``held_experts``) takes the dropless
+    ``held_experts_apply`` instead, with no aux loss.
 
     On a mesh, tokens are routed *per data shard* under shard_map (expert
     weights ff-sharded over `model` — expert tensor parallelism) with a psum
     over `model` for the down-projection partial sums.
     """
+    if cfg.held_experts:
+        return held_experts_apply(p, x, cfg), jnp.zeros((), jnp.float32)
     B, S, d = x.shape
     xf = x.reshape(B * S, d)
     mesh = _mesh_ctx()

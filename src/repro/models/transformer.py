@@ -8,7 +8,9 @@ entirely by ``ArchConfig``. Layers are stacked and scanned with
 
 Layer layout per family:
   dense/moe/vlm/audio : blocks stacked (L, ...); gemma2 scans (L/2, 2, ...)
-                        pairs of (local-window, global) layers.
+                        pairs of (local-window, global) layers; a config
+                        with a ``layer_pattern`` scans each layer's kind
+                        as data beside its weights.
   ssm                 : mamba blocks stacked (L, ...).
   hybrid (zamba2)     : mamba blocks scanned in groups of
                         ``shared_attn_every``; one *shared* attention+mlp
@@ -32,6 +34,9 @@ Params = Dict[str, Any]
 
 CHUNKED_ATTN_THRESHOLD = 8192  # prefill longer than this uses online-softmax
 ATTN_CHUNK = 1024
+# the window of a full-attention layer whose kind is traced data: wider
+# than any position, so it masks nothing
+NO_WINDOW = 2**30
 
 
 # ---------------------------------------------------------------------------
@@ -86,13 +91,32 @@ def init_params(key, cfg: ArchConfig) -> Params:
 # ---------------------------------------------------------------------------
 # block bodies
 # ---------------------------------------------------------------------------
-def _attn_block_seq(bp, x, cfg, positions, window, chunked, collect_kv):
+def _kind_flags(cfg: ArchConfig) -> jax.Array:
+    """(L,) bool, True where a layer of ``cfg.layer_pattern`` is local: the
+    layer kinds scanned as data through the stacked blocks."""
+    return jnp.asarray([k == "local" for k in cfg.layer_kinds()])
+
+
+def _kind_window(cfg: ArchConfig, local: jax.Array) -> jax.Array:
+    """The attention window of a layer whose kind is the traced ``local``."""
+    return jnp.where(local, cfg.sliding_window or NO_WINDOW, NO_WINDOW)
+
+
+def _rope_kw(cfg: ArchConfig, local) -> dict:
+    """YaRN on the full layers: on every layer where kinds are not data."""
+    return {"yarn": cfg.rope_yarn,
+            "use_yarn": None if local is None else jnp.logical_not(local)}
+
+
+def _attn_block_seq(bp, x, cfg, positions, window, chunked, collect_kv,
+                    local=None):
     from repro.models.runtime_flags import FLAGS
 
     h, kv = L.attn_apply_seq(
         bp["attn"], L.rms_norm(x, bp["ln1"], cfg.norm_eps), cfg, positions,
         window=window, chunked=chunked,
         chunk=int(FLAGS.get("attn_chunk", ATTN_CHUNK)),
+        **_rope_kw(cfg, local),
     )
     x = x + h
     xn = L.rms_norm(x, bp["ln2"], cfg.norm_eps)
@@ -190,6 +214,19 @@ def forward(
             aux_total = auxs.sum()
             if collect_cache:
                 cache = {"local": kvs[0], "global": kvs[1]}
+        elif cfg.layer_pattern:
+            def body(x, xs):
+                bp, loc = xs
+                x, a, kv = _attn_block_seq(bp, x, cfg, positions,
+                                           _kind_window(cfg, loc), chunked,
+                                           collect_cache, local=loc)
+                return x, (a, kv)
+            if remat:
+                body = jax.checkpoint(body)
+            x, (auxs, kvs) = jax.lax.scan(body, x, (blocks, _kind_flags(cfg)))
+            aux_total = auxs.sum()
+            if collect_cache:
+                cache = {"kv": kvs}
         else:
             def body(x, bp):
                 x, a, kv = _attn_block_seq(bp, x, cfg, positions, window, chunked, collect_cache)
@@ -308,6 +345,9 @@ def init_decode_state(cfg: ArchConfig, batch: int, context_len: int) -> Params:
                 "k_local": kv(Lr // 2, Wl), "v_local": kv(Lr // 2, Wl),
                 "k_global": kv(Lr // 2, context_len), "v_global": kv(Lr // 2, context_len),
             }
+        if cfg.layer_pattern:
+            # kinds mixed in one stack: every layer keeps the full length
+            return {"k": kv(Lr, context_len), "v": kv(Lr, context_len)}
         W = min(cfg.sliding_window, context_len) if cfg.sliding_window else context_len
         from repro.models.runtime_flags import FLAGS
         if FLAGS.get("kv_cache_int8", False):
@@ -332,10 +372,10 @@ def init_decode_state(cfg: ArchConfig, batch: int, context_len: int) -> Params:
     raise ValueError(cfg.family)
 
 
-def _attn_block_decode(bp, x, ck, cv, pos, cfg, window):
+def _attn_block_decode(bp, x, ck, cv, pos, cfg, window, local=None):
     h, (ck, cv) = L.attn_decode_step(
         bp["attn"], L.rms_norm(x, bp["ln1"], cfg.norm_eps), ck, cv, pos, cfg,
-        window=window,
+        window=window, **_rope_kw(cfg, local),
     )
     x = x + h
     xn = L.rms_norm(x, bp["ln2"], cfg.norm_eps)
@@ -386,6 +426,15 @@ def decode_step(
                 body, x, (blocks2, state["k_local"], state["v_local"],
                           state["k_global"], state["v_global"]))
             state = {"k_local": kl, "v_local": vl, "k_global": kg, "v_global": vg}
+        elif cfg.layer_pattern:
+            def body(x, xs):
+                bp, ck, cv, loc = xs
+                x, ck, cv = _attn_block_decode(bp, x, ck, cv, pos, cfg,
+                                               _kind_window(cfg, loc), loc)
+                return x, (ck, cv)
+            x, (ck, cv) = jax.lax.scan(
+                body, x, (blocks, state["k"], state["v"], _kind_flags(cfg)))
+            state = {"k": ck, "v": cv}
         else:
             window = cfg.sliding_window
             quant = "k_scale" in state

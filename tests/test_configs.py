@@ -15,11 +15,13 @@ NOMINAL = {
     "gemma2-27b": 27e9,
     "internvl2-76b": 76e9,      # incl. vision tower; LLM part ≈ 70B
     "qwen3-32b": 32e9,
+    "mellum2-12b-a2.5b": 12e9,
 }
 
 ACTIVE = {
     "granite-moe-3b-a800m": 0.8e9,
     "qwen3-moe-30b-a3b": 3e9,
+    "mellum2-12b-a2.5b": 2.5e9,
 }
 
 

@@ -4,7 +4,8 @@ Nothing runs: each program is lowered against shapes placed on a *described*
 v5e device and compiled by the TPU compiler that ships with ``libtpu``. That
 catches what interpret mode and the CPU backend cannot — Mosaic refusing a
 Pallas kernel, a program that does not fit, a layout the chip cannot take —
-at no chip time. Shapes are smollm-360m's published widths.
+at no chip time. Shapes are smollm-360m's published widths, and
+Mellum2-12B-A2.5B's for the sparse-expert blocks and their decode step.
 
 The topology is described inside a module-scoped fixture (never at import
 time): only one process may load the TPU library, and only the test worker
@@ -103,6 +104,53 @@ def test_decode_step_compiles(one_chip):
         lambda: T.init_params(jax.random.PRNGKey(0), cfg)))
     state = jax.tree.map(place, jax.eval_shape(
         lambda: T.init_decode_state(cfg, 1, PROMPT + 16)))
+    batch = {"tokens": _sds((1, 1), jnp.int32, one_chip)}
+    pos = _sds((), jnp.int32, one_chip)
+    _compile(lambda p, s, b, i: T.decode_step(p, s, b, i, cfg),
+             params, state, batch, pos)
+
+
+def _mellum_ep8():
+    """Mellum 2 at its published widths as one chip of eight holds it:
+    the router over 64 experts, experts 0-7 held."""
+    import dataclasses
+
+    return dataclasses.replace(get_config("mellum2-12b-a2.5b"),
+                               held_experts=tuple(range(8)))
+
+
+@pytest.mark.parametrize("kernel", ["bf16_cast", "f32_direct"])
+@pytest.mark.parametrize("kind", ["local", "attn"])
+def test_moeblock_execute_compiles(one_chip, kind, kernel):
+    from repro.core.llm_graph import build_llm_graph_specs
+
+    cfg = _mellum_ep8()
+    spec = next(s for s in build_llm_graph_specs(cfg)
+                if s.op_type == "moeblock" and s.config["kind"] == kind)
+    assert spec.weight_shapes["expert_w_up"] == (8, 2304, 896)
+    kern = _kernel("moeblock", kernel)
+    dt = jnp.bfloat16 if kernel == "bf16_cast" else jnp.float32
+    w = {k: _sds(s, dt, one_chip) for k, s in spec.weight_shapes.items()}
+    x = _sds((1, 128, cfg.d_model), jnp.bfloat16, one_chip)
+    out = _compile(lambda w, x: kern.execute(w, x, spec), w, x)
+    assert out.out_info.shape == (1, 128, cfg.d_model)
+
+
+def test_moe_decode_step_compiles(one_chip):
+    """Two periods' worth of kinds scanned as data: one sliding and one
+    full layer at the published widths, the whole vocabulary."""
+    from repro.models import transformer as T
+
+    cfg = _mellum_ep8().reduced(
+        num_layers=4, d_model=2304, d_ff=896, vocab_size=98304,
+        num_heads=32, num_kv_heads=4, head_dim=128, num_experts=64,
+        top_k=8, sliding_window=1024)
+    assert cfg.layer_kinds() == ("local", "local", "local", "attn")
+    place = lambda a: _sds(a.shape, a.dtype, one_chip)  # noqa: E731
+    params = jax.tree.map(place, jax.eval_shape(
+        lambda: T.init_params(jax.random.PRNGKey(0), cfg)))
+    state = jax.tree.map(place, jax.eval_shape(
+        lambda: T.init_decode_state(cfg, 1, 128 + 34)))
     batch = {"tokens": _sds((1, 1), jnp.int32, one_chip)}
     pos = _sds((), jnp.int32, one_chip)
     _compile(lambda p, s, b, i: T.decode_step(p, s, b, i, cfg),
