@@ -126,6 +126,10 @@ class ColdEngine:
         self._input_example: Optional[np.ndarray] = None
         self._layer_inputs: Optional[List[np.ndarray]] = None
         self._jitted_cache: Dict[tuple, Dict[str, Callable]] = {}
+        # the cold LLM bridge's jitted decode steps, one per configuration,
+        # kept across cold starts as the prefill's executables are
+        # (``serving.server.decode_step_for``)
+        self.decode_steps: Dict[Any, Callable] = {}
         self._sc_by_layer: Dict[str, str] = {}
         self._sib_by_sc: Dict[str, Optional[str]] = {}
         # shape classes whose decide() profiles came from a drifted-host

@@ -14,7 +14,11 @@ A cold LLM start becomes a first-token-optimal pipeline:
      layer's decode prep always completes after the first token is out;
   3. once every pack landed, the stacked decode params feed a
      ``BatchedServer`` that replays the prompt (+ the already-emitted first
-     token) into a KV slot and continues decoding.
+     token) into a KV slot and continues decoding. The server takes the
+     engine's decode step for the configuration (``ColdEngine.
+     decode_steps``), shared across servers and cold starts: only the
+     engine's first cold start of a configuration and shape traces, lowers
+     and loads it.
 
 The result records the first-token timestamp against the job clock next to
 the prep/pack trace ends, and every span of the cold start on that clock
@@ -194,7 +198,8 @@ def cold_start_llm(
         srv = BatchedServer(params, cfg, max_batch=1,
                             max_len=int(prompt.size + max_new_tokens + 2),
                             budget=(server.budget if server is not None
-                                    else None))
+                                    else None),
+                            steps=engine.decode_steps)
     tokens = [first_token]
     rows = [logits[0, -1]]
     if max_new_tokens > 1:

@@ -10,6 +10,13 @@ packed into the slot.
 Combined with the ColdEngine, a cold-started server overlaps model weight
 loading with the first prefill (examples/serve_cold.py).
 
+A server takes its jitted decode step from a memo of steps per
+configuration that its owner keeps (``decode_step_for``): the servers of
+a ``ColdEngine``'s cold starts share one step per configuration, so a new
+server neither traces nor lowers it again; JAX's cache inside the step
+keys the shapes (batch, ``max_len``, the params' pytree). ``COUNTERS``
+counts ``decode_step_builds`` and ``decode_step_reuses``.
+
 Each tick is a ``decode.step`` span and each token's pick a ``decode.pick``
 span (``executor.graph.span``), recorded into the cold start whose thread
 runs the server. A pick holds a ``decode.wait`` span: the host waiting for
@@ -17,9 +24,11 @@ the device's token id; the rest of the pick is host work.
 """
 from __future__ import annotations
 
+import collections
+import threading
 import time
 from dataclasses import dataclass, field
-from typing import Dict, List, Optional
+from typing import Callable, Dict, List, Optional
 
 import jax
 import jax.numpy as jnp
@@ -28,6 +37,33 @@ import numpy as np
 from repro.configs.base import ArchConfig
 from repro.executor.graph import span
 from repro.models import transformer as T
+
+COUNTERS: collections.Counter = collections.Counter()
+_steps_lock = threading.Lock()
+
+
+def decode_step_for(cfg: ArchConfig,
+                    steps: Optional[Dict[ArchConfig, Callable]] = None
+                    ) -> Callable:
+    """The jitted decode step for ``cfg``: the one ``steps`` holds for an
+    equal configuration, else a new one, kept in ``steps`` where given.
+    ``steps`` is a memo its owner keeps across servers, as a
+    ``ColdEngine``'s ``decode_steps`` across its cold starts. Named, so its
+    program reads ``jit_decode_step`` in a trace."""
+    with _steps_lock:
+        step = steps.get(cfg) if steps is not None else None
+        if step is not None:
+            COUNTERS["decode_step_reuses"] += 1
+            return step
+
+        def decode_step(p, s, b, pos):
+            return T.decode_step(p, s, b, pos, cfg)
+
+        step = jax.jit(decode_step)
+        if steps is not None:
+            steps[cfg] = step
+        COUNTERS["decode_step_builds"] += 1
+        return step
 
 
 def sample_token(logits: jax.Array, key, *, temperature: float = 0.0,
@@ -69,13 +105,19 @@ class Request:
 
 class BatchedServer:
     def __init__(self, params, cfg: ArchConfig, *, max_batch: int = 4,
-                 max_len: int = 512, budget=None):
+                 max_len: int = 512, budget=None,
+                 steps: Optional[Dict[ArchConfig, Callable]] = None):
         """``budget`` (a ``repro.executor.server.MemoryBudget``, duck-typed
         ``reserve``/``release``) charges this server's KV-cache allocation
         to the SAME accounted pool the ColdServer's staged-weight LRU draws
         from: allocating KV for decode may evict another model's resident
         weights instead of silently overcommitting device memory.
-        ``close()`` releases the reservation."""
+        ``close()`` releases the reservation.
+
+        ``steps`` is the memo of jitted decode steps per configuration that
+        the server takes its step from (``decode_step_for``): a
+        ``ColdEngine``'s ``decode_steps``, shared across its cold starts.
+        Without one the server builds a step of its own."""
         assert cfg.input_mode == "tokens", "server demo expects token models"
         self.params = params
         self.cfg = cfg
@@ -95,11 +137,9 @@ class BatchedServer:
         # to the caller (a long-running server must not accumulate every
         # request it ever served)
         self.finished: List[Request] = []
-        def decode_step(p, s, b, pos):
-            return T.decode_step(p, s, b, pos, cfg)
-
-        # named, so the program reads ``jit_decode_step`` in a trace
-        self._decode = jax.jit(decode_step)
+        # looked up once here: a static ``cfg`` argument would hash the
+        # config on every dispatch
+        self._decode = decode_step_for(cfg, steps)
         self._t0 = time.perf_counter()
         self._key = jax.random.PRNGKey(0)
 

@@ -27,20 +27,33 @@ def _cfg():
 
 
 @pytest.fixture(scope="module")
-def cold(tmp_path_factory):
-    """One cold start, traced by the profiler: (result, its job's seq,
-    the profiler's host events)."""
-    from jax.profiler import ProfileData
-
+def model(tmp_path_factory):
+    """(server, engine, prompt ids) of the tiny LLM, decided; the engine
+    has built no decode step yet."""
     graph, toks = tiny_llm_graph(LAYERS)
     srv = ColdServer(tmp_path_factory.mktemp("server"), n_little=2)
     eng = srv.add_model("llm", graph)
     srv.decide("llm", toks, n_little=2)
+    return srv, eng, toks
+
+
+def _cold_start(model):
+    srv, eng, toks = model
+    srv.evict("llm")
+    return cold_start_llm(eng, _cfg(), toks[0],
+                          max_new_tokens=NEW_TOKENS, n_little=2,
+                          server=srv, model_name="llm")
+
+
+@pytest.fixture(scope="module")
+def cold(model, tmp_path_factory):
+    """The engine's first cold start, traced by the profiler:
+    (result, its job's seq, the profiler's host events)."""
+    from jax.profiler import ProfileData
+
     logdir = tmp_path_factory.mktemp("trace")
     with jax.profiler.trace(str(logdir)):
-        res = cold_start_llm(eng, _cfg(), toks[0],
-                             max_new_tokens=NEW_TOKENS, n_little=2,
-                             server=srv, model_name="llm")
+        res = _cold_start(model)
     xplane, = glob.glob(f"{logdir}/**/*.xplane.pb", recursive=True)
     events = [(e.name, dict(e.stats), e.duration_ns / 1e9)
               for plane in ProfileData.from_file(xplane).planes
@@ -89,13 +102,18 @@ def test_one_pick_per_decoded_token(cold):
 
 
 @pytest.mark.parametrize("kind", ["compile", "gc"])
-def test_compiles_and_collections_become_spans(cold, kind):
+def test_compiles_and_collections_become_spans(model, cold, kind):
     res, seq, _ = cold
     if kind == "compile":
-        # the fresh decode server traces its step again, at least
+        # an engine's first cold start of a configuration traces its decode
+        # step; a second one takes the step built then and traces none of it
         found = [t for t in res.spans if t.kind == "compile"]
         assert any("decode_step" in t.detail for t in found)
         assert all(t.end >= t.start for t in found)
+        again = _cold_start(model)
+        assert again.tokens == res.tokens
+        assert not any("decode_step" in t.detail for t in again.spans
+                       if t.kind == "compile")
     else:
         t0 = time.perf_counter()
         gc.collect()
