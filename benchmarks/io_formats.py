@@ -1,11 +1,11 @@
-"""Cold weight-read formats: per-tensor .npy vs packed bundle vs model-level
+"""Cold weight-read formats: packed per-layer bundle vs model-level
 super-bundle.
 
 Measures the per-layer 'weights reading' op the scheduler pipelines, across
 the on-disk layouts the ``LayerStore`` supports:
 
-  npy          legacy: one file per tensor, N opens + N full copies
-  bundle       packed single-blob layer file, ONE open + one sequential read
+  bundle       packed single-blob layer file, ONE open + one sequential
+               read — the reference arm every speedup is relative to
   bundle_mmap  same file, zero-copy ``np.memmap`` views — the read op is
                metadata-only; payload pages fault in later, inside
                transform/stage, off the critical exec chain
@@ -18,10 +18,10 @@ the on-disk layouts the ``LayerStore`` supports:
 
 The super-bundle store is built with ``superbundle.migrate`` from the
 per-layer bundle tree, so the migration path is exercised on every run.
-Every run cross-checks tensor equivalence across all formats and counts
-the file opens a full-model sweep performs (npy: N_tensors, bundle:
-N_layers, super: 1) — both are hard failures on mismatch, which is what
-CI runs ``--smoke`` for.
+Every run cross-checks tensor equivalence across all formats and read
+modes against the bundle's materializing read, and counts the file opens
+a full-model sweep performs (bundle: N_layers, super: 1) — both are hard
+failures on mismatch, which is what CI runs ``--smoke`` for.
 
 The durability arms (``bench_durability``) exercise the v3 container's
 crash-atomicity layer and are also hard gates in ``--smoke``:
@@ -39,8 +39,8 @@ crash-atomicity layer and are also hard gates in ``--smoke``:
   compaction        dropped entries leave dead extents; ``compact`` must
                     reclaim them to exactly zero slack beyond alignment
 
-Workloads: cnn_zoo models (2 tensors/layer — worst case for bundling) and
-an LLM decoder graph (10+ tensors per tblock — where N-opens hurt most).
+Workloads: cnn_zoo models (2 tensors/layer) and an LLM decoder graph (10+
+tensors per tblock).
 
 Run: PYTHONPATH=src python benchmarks/io_formats.py [--smoke]
 """
@@ -123,34 +123,35 @@ def _count_opens(store: LayerStore, names: List[str]) -> int:
 
 
 def _check_equivalence(stores: Dict[str, LayerStore], names: List[str]):
-    """Every format must return identical tensors for every layer — a
-    mismatch is a hard failure (CI gates on it)."""
-    ref = stores["npy"]
+    """Every format and read mode must return the bundle's materialized
+    tensors for every layer — a mismatch is a hard failure (CI gates on
+    it)."""
+    ref = stores["bundle"]
     for n in names:
-        want = ref.read_raw(n)
+        want = ref.read_raw(n, mmap=False)
         for label, st in stores.items():
-            if st is ref:
-                continue
-            got = st.read_raw(n, mmap=False)
-            if set(got) != set(want):
-                raise AssertionError(
-                    f"equivalence mismatch: {label}/{n} keys {set(got)} "
-                    f"!= npy keys {set(want)}")
-            for k in want:
-                if got[k].dtype != want[k].dtype or not np.array_equal(
-                        np.asarray(got[k]), np.asarray(want[k])):
+            for mmap in (False, True):
+                if st is ref and not mmap:
+                    continue
+                got = st.read_raw(n, mmap=mmap)
+                arm = f"{label}{'_mmap' if mmap else ''}/{n}"
+                if set(got) != set(want):
                     raise AssertionError(
-                        f"equivalence mismatch: {label}/{n}/{k}")
+                        f"equivalence mismatch: {arm} keys {set(got)} "
+                        f"!= bundle keys {set(want)}")
+                for k in want:
+                    if got[k].dtype != want[k].dtype or not np.array_equal(
+                            np.asarray(got[k]), np.asarray(want[k])):
+                        raise AssertionError(
+                            f"equivalence mismatch: {arm}/{k}")
 
 
 def bench_model(name: str, weights: Dict[str, dict], repeats: int = 3,
                 print_csv: bool = True) -> Dict[str, float]:
     names = list(weights)
     with tempfile.TemporaryDirectory(prefix=f"iofmt_{name}_") as td:
-        s_npy = LayerStore(Path(td) / "npy", fmt="npy")
         s_bun = LayerStore(Path(td) / "bundle", fmt="bundle")
         for ln, w in weights.items():
-            s_npy.write_raw(ln, w)
             s_bun.write_raw(ln, w)
         # super store: migrated from the per-layer bundle tree, laid out in
         # graph order — exercises the migration path every run. verify=never:
@@ -161,10 +162,8 @@ def bench_model(name: str, weights: Dict[str, dict], repeats: int = 3,
         migrate(Path(td) / "bundle", Path(td) / "super" / "model.superbundle",
                 order=names)
 
-        _check_equivalence(
-            {"npy": s_npy, "bundle": s_bun, "super": s_sup}, names)
+        _check_equivalence({"bundle": s_bun, "super": s_sup}, names)
         opens = {
-            "npy": _count_opens(s_npy, names),
             "bundle": _count_opens(s_bun, names),
             "super": _count_opens(s_sup, names),
         }
@@ -172,7 +171,6 @@ def bench_model(name: str, weights: Dict[str, dict], repeats: int = 3,
             f"super-bundle must be ONE open per model, saw {opens['super']}")
         assert opens["bundle"] == len(names), opens
 
-        t_npy = _sweep(lambda n: s_npy.read_raw(n), names, repeats)
         t_bun = _sweep(lambda n: s_bun.read_raw(n, mmap=False), names, repeats)
         t_map = _sweep(lambda n: s_bun.read_raw(n, mmap=True), names, repeats)
         t_map_touch = _sweep(
@@ -187,24 +185,19 @@ def bench_model(name: str, weights: Dict[str, dict], repeats: int = 3,
 
     per_layer = 1.0 / max(len(names), 1)
     res = {
-        "npy_s": t_npy, "bundle_s": t_bun, "bundle_mmap_s": t_map,
+        "bundle_s": t_bun, "bundle_mmap_s": t_map,
         "bundle_mmap_touch_s": t_map_touch,
         "super_s": t_sup, "super_mmap_s": t_sup_map,
         "super_mmap_touch_s": t_sup_touch,
-        "opens_npy": opens["npy"], "opens_bundle": opens["bundle"],
-        "opens_super": opens["super"],
-        "speedup_bundle": t_npy / max(t_bun, 1e-9),
-        "speedup_mmap": t_npy / max(t_map, 1e-9),
-        "speedup_mmap_touch": t_npy / max(t_map_touch, 1e-9),
-        "speedup_super": t_npy / max(t_sup, 1e-9),
-        "speedup_super_mmap": t_npy / max(t_sup_map, 1e-9),
+        "opens_bundle": opens["bundle"], "opens_super": opens["super"],
+        "speedup_mmap": t_bun / max(t_map, 1e-9),
+        "speedup_mmap_touch": t_bun / max(t_map_touch, 1e-9),
+        "speedup_super": t_bun / max(t_sup, 1e-9),
+        "speedup_super_mmap": t_bun / max(t_sup_map, 1e-9),
     }
     if print_csv:
-        print(csv_line(f"io_formats/{name}/npy", t_npy * per_layer,
-                       f"layers={len(names)};opens={opens['npy']}"))
         print(csv_line(f"io_formats/{name}/bundle", t_bun * per_layer,
-                       f"speedup={res['speedup_bundle']:.2f}x"
-                       f";opens={opens['bundle']}"))
+                       f"layers={len(names)};opens={opens['bundle']}"))
         print(csv_line(f"io_formats/{name}/bundle_mmap", t_map * per_layer,
                        f"speedup={res['speedup_mmap']:.2f}x"))
         print(csv_line(f"io_formats/{name}/bundle_mmap_touch",
@@ -216,7 +209,7 @@ def bench_model(name: str, weights: Dict[str, dict], repeats: int = 3,
                        f"speedup={res['speedup_super_mmap']:.2f}x;opens=1"))
         print(csv_line(f"io_formats/{name}/super_mmap_touch",
                        t_sup_touch * per_layer,
-                       f"speedup={t_npy / max(t_sup_touch, 1e-9):.2f}x"))
+                       f"speedup={t_bun / max(t_sup_touch, 1e-9):.2f}x"))
         ok = t_sup_map <= t_map
         print(f"# {name}: super_mmap <= bundle_mmap: {ok} "
               f"({t_sup_map * per_layer * 1e6:.1f} vs "

@@ -196,11 +196,12 @@ def serve_and_check(cfg, workdir: Path) -> None:
           f"degradation ladder recorded nothing ({repairs})")
     check(not eng.breaker.open_keys(), "no circuit breaker opened")
 
-    io_eng, st_eng = eng._resolve_io_engines()
+    from repro.core import staging
+    from repro.ioengine import get_io_engine
+
     cc = eng.compile_cache.stats
-    print(f"I/O backend {io_eng.name if io_eng else 'sync'}; stage engine "
-          f"{st_eng.name if st_eng else 'inline'} "
-          f"({st_eng.stats if st_eng else {}})", flush=True)
+    print(f"I/O backend {get_io_engine().name}; staging route "
+          f"{staging.route_name()}", flush=True)
     print(f"executable cache: {cc['misses']} compiled "
           f"({cc['compile_s']:.2f}s), {cc['disk_hits']} from disk, "
           f"{cc['hits']} in memory, {cc['deserialize_failures']} unreadable",

@@ -23,13 +23,11 @@ Two facilities:
     path — crash-atomic since format v3 (intent journal + per-extent
     CRC-32C; ``verify=`` picks the checksum-audit mode and ``maintain()``
     compacts dead cache extents — see ``checkpoint/superbundle.py`` and
-    ``docs/formats.md``). ``fmt="npy"`` keeps the legacy per-tensor ``.npy`` layout (one
-    file per tensor, bf16 stored as uint16 views) for format benchmarks
-    and the bundle-vs-legacy equivalence tests.
+    ``docs/formats.md``).
 
     ``open_count`` tracks the file opens the read path performs (the
-    number the cold-I/O benchmarks compare across formats: N_tensors for
-    npy, N_layers for bundle, 1 per model for super).
+    number the cold-I/O benchmarks compare across formats: N_layers for
+    bundle, 1 per model for super).
 
   * pytree checkpointing (``save_pytree``/``load_pytree``) for the training
     loop — flat .npy files keyed by the pytree path.
@@ -38,7 +36,6 @@ from __future__ import annotations
 
 import json
 import os
-import shutil
 from pathlib import Path
 from typing import Any, Dict, List, Optional, Set, Tuple
 
@@ -67,7 +64,7 @@ def _safe(name: str) -> str:
 # ---------------------------------------------------------------------------
 class _ImmediateRead:
     """Pending-read interface over bytes already in hand (buffered
-    super-bundle writes, npy fallback): wait() returns instantly."""
+    super-bundle writes, absent entries): wait() returns instantly."""
 
     def __init__(self, weights: Dict[str, np.ndarray]):
         self._w = weights
@@ -165,35 +162,6 @@ class _PendingBundleRead:
             self._fd = None
 
 
-# ---------------------------------------------------------------------------
-# legacy per-tensor .npy layout (fmt="npy")
-# ---------------------------------------------------------------------------
-def _save_arr(path_base: Path, v: np.ndarray):
-    """np.save with bf16 support (stored as uint16 + .bf16.npy suffix —
-    numpy cannot round-trip ml_dtypes through .npy)."""
-    import ml_dtypes
-
-    v = np.asarray(v)
-    if v.dtype == ml_dtypes.bfloat16:
-        np.save(path_base.with_suffix(".bf16.npy"), v.view(np.uint16),
-                allow_pickle=False)
-    else:
-        np.save(path_base.with_suffix(".npy"), v, allow_pickle=False)
-
-
-def _load_dir(d: Path) -> Dict[str, np.ndarray]:
-    import ml_dtypes
-
-    out: Dict[str, np.ndarray] = {}
-    for p in sorted(d.glob("*.npy")):
-        if p.name.endswith(".bf16.npy"):
-            out[p.name[: -len(".bf16.npy")]] = np.load(
-                p, allow_pickle=False).view(ml_dtypes.bfloat16)
-        else:
-            out[p.stem] = np.load(p, allow_pickle=False)
-    return out
-
-
 class LayerStore:
     """Per-layer weight store. ``fmt="bundle"`` (default) packs each layer
     into one aligned blob; ``fmt="super"`` packs the whole model into one;
@@ -202,7 +170,7 @@ class LayerStore:
 
     def __init__(self, root: Path, *, fmt: str = "bundle", mmap: bool = True,
                  verify: str = "lazy"):
-        assert fmt in ("bundle", "npy", "super"), fmt
+        assert fmt in ("bundle", "super"), fmt
         assert verify in ("never", "lazy", "eager"), verify
         self.root = Path(root)
         self.fmt = fmt
@@ -453,33 +421,22 @@ class LayerStore:
         return stats
 
     # -- layout -------------------------------------------------------------
+    # NOT with_suffix: dotted layer names ("block.0") must not collide
     def _raw_path(self, layer: str) -> Path:
-        base = self.root / "raw" / _safe(layer)
-        # NOT with_suffix: dotted layer names ("block.0") must not collide
-        return base.parent / (base.name + ".bundle") if self.fmt == "bundle" else base
+        return self.root / "raw" / (_safe(layer) + ".bundle")
 
     def _cache_path(self, layer: str, kernel: str) -> Path:
-        base = self.root / "cache" / kernel / _safe(layer)
-        return base.parent / (base.name + ".bundle") if self.fmt == "bundle" else base
+        return self.root / "cache" / kernel / (_safe(layer) + ".bundle")
 
     def _write(self, path: Path, weights: Dict[str, np.ndarray]):
-        if self.fmt == "bundle":
-            path.parent.mkdir(parents=True, exist_ok=True)
-            write_bundle(path, weights)
-        else:
-            path.mkdir(parents=True, exist_ok=True)
-            for k, v in weights.items():
-                _save_arr(path / k, v)
+        path.parent.mkdir(parents=True, exist_ok=True)
+        write_bundle(path, weights)
 
     def _read(self, path: Path, mmap: Optional[bool]) -> Dict[str, np.ndarray]:
         if not path.exists():
             return {}  # weightless (stateless) layers have no file on disk
-        if self.fmt == "bundle":
-            use = self.mmap if mmap is None else mmap
-            self.open_count += 1
-            return read_bundle(path, mmap=use)
-        self.open_count += sum(1 for _ in path.glob("*.npy"))
-        return _load_dir(path)
+        self.open_count += 1
+        return read_bundle(path, mmap=self.mmap if mmap is None else mmap)
 
     # -- raw weights --------------------------------------------------------
     def write_raw(self, layer: str, weights: Dict[str, np.ndarray]):
@@ -515,9 +472,7 @@ class LayerStore:
             sb = self._super()
             return sb.raw_nbytes(layer) if sb is not None else 0
         p = self._raw_path(layer)
-        if self.fmt == "bundle":
-            return p.stat().st_size if p.exists() else 0
-        return sum(q.stat().st_size for q in p.glob("*.npy"))
+        return p.stat().st_size if p.exists() else 0
 
     def cached_bytes(self, layer: str, kernel: str) -> int:
         """Extent bytes a cold read of one cache entry costs. For
@@ -537,9 +492,7 @@ class LayerStore:
             return sum(e["nbytes"]
                        for e in sb._layers[layer]["cache"][kernel])
         p = self._cache_path(layer, kernel)
-        if self.fmt == "bundle":
-            return p.stat().st_size if p.exists() else 0
-        return sum(q.stat().st_size for q in p.glob("*.npy"))
+        return p.stat().st_size if p.exists() else 0
 
     # -- post-transformed cache (§3.1.2) ------------------------------------
     def write_cached(self, layer: str, kernel: str, weights: Dict[str, np.ndarray]):
@@ -585,13 +538,6 @@ class LayerStore:
             raise f from e
 
     # -- async submit/reap reads (repro.ioengine) ---------------------------
-    @property
-    def supports_async(self) -> bool:
-        """True when reads can go through the async I/O engine (the npy
-        legacy layout stays sync — its N-tiny-files shape is the thing
-        the benchmarks keep it around to demonstrate)."""
-        return self.fmt in ("super", "bundle")
-
     def submit_read_raw(self, engine, layer: str):
         """Submit ``layer``'s raw extents to the async engine; returns a
         pending-read handle (``wait()``/``nbytes()``/``release()``).  The
@@ -607,14 +553,11 @@ class LayerStore:
                                        injector=self.fault_injector)
                         if sb is not None else None)
                 return pend if pend is not None else _ImmediateRead({})
-            if self.fmt == "bundle":
-                p = self._raw_path(layer)
-                if not p.exists():
-                    return _ImmediateRead({})
-                return _PendingBundleRead(self, p, engine,
-                                          self.fault_injector,
-                                          key=layer).submit()
-            return _ImmediateRead(self._read(self._raw_path(layer), False))
+            p = self._raw_path(layer)
+            if not p.exists():
+                return _ImmediateRead({})
+            return _PendingBundleRead(self, p, engine, self.fault_injector,
+                                      key=layer).submit()
         except OSError as e:
             f = classify(e, site="store.read_raw", layer=layer)
             if f is e:
@@ -644,49 +587,21 @@ class LayerStore:
                     return _ImmediateRead({})
                 pend.on_drop = self._harvest_drops
                 return pend
-            if self.fmt == "bundle":
-                p = self._cache_path(layer, kernel)
-                if not p.exists():
-                    return _ImmediateRead({})
-                return _PendingBundleRead(self, p, engine,
-                                          self.fault_injector,
-                                          key=f"{layer}@{kernel}").submit()
-            return _ImmediateRead(
-                self._read(self._cache_path(layer, kernel), False))
+            p = self._cache_path(layer, kernel)
+            if not p.exists():
+                return _ImmediateRead({})
+            return _PendingBundleRead(self, p, engine, self.fault_injector,
+                                      key=f"{layer}@{kernel}").submit()
         except OSError as e:
             f = classify(e, site="store.read_cached", layer=layer)
             if f is e:
                 raise
             raise f from e
 
-    def audit_cached(self, layer: str, kernel: str) -> bool:
-        """Run the lazy CRC audit on a cache entry NOW, covering the
-        zero-copy mmap path (which normally serves views unverified). The
-        runtime's degradation ladder calls this before trusting a cached
-        entry mid-run: a failing extent is dropped from the header
-        (reported via ``dropped_entries``) and the caller transparently
-        recomputes the transform from raw. Returns False exactly when the
-        entry just failed its audit; True when it verifies, is still
-        buffered, is absent (``read_cached`` returns ``{}`` anyway), or
-        auditing is off (non-super format / ``verify="never"``)."""
-        if self.fmt != "super" or self.verify == "never":
-            return True
-        if (layer, kernel) in self._pending_cache:
-            return True
-        if (layer, kernel) in self._pending_drop:
-            return False
-        sb = self._super()
-        if sb is None or not sb.has_cached(layer, kernel):
-            return True
-        ok = sb._verify_cached(layer, kernel)
-        if not ok:
-            self._harvest_drops()
-        return ok
-
     def _harvest_drops(self) -> None:
         """Sync the reader's drop reports into ``dropped_entries`` NOW, so
         a repair event can cite the reason without waiting for the reader
-        to reopen (audit failures and async CRC drops both land here)."""
+        to reopen (an async read's CRC drop lands here)."""
         sb = self._reader
         if sb is None:
             return
@@ -715,11 +630,7 @@ class LayerStore:
                 self._invalidate_reader()
                 drop_cache_entry(self._super_path, layer, kernel)
             return
-        p = self._cache_path(layer, kernel)
-        if p.is_dir():
-            shutil.rmtree(p)
-        elif p.exists():
-            p.unlink()
+        self._cache_path(layer, kernel).unlink(missing_ok=True)
 
     # -- storage accounting (real on-disk footprint) ------------------------
     def cache_bytes(self) -> int:

@@ -45,7 +45,7 @@ from repro.core.registry import (
 from repro.core.scheduler import (
     Choice, LayerCandidates, Plan, pareto_filter, plan_read_depth, schedule,
 )
-from repro.core.staging import stage_weights
+from repro.core.staging import stage
 from repro.faults import (
     CircuitBreaker, Fault, KernelFault, PlanFault, RepairLog,
 )
@@ -75,8 +75,6 @@ class ColdEngine:
         profile_db: Union[str, Path, ProfileDB, None] = "auto",
         profile_db_approx: bool = False,
         pool: Optional[CorePool] = None,
-        io_engine: Any = "auto",
-        stage_engine: Any = "auto",
     ):
         self.layers = layers
         self.specs = [l.spec for l in layers]
@@ -108,11 +106,6 @@ class ColdEngine:
         # to a sibling class identical up to the batch dim (exact first)
         self.profile_db_approx = profile_db_approx
         self.pool = pool                  # shared persistent CorePool
-        # async prep I/O: "auto" resolves to the process-wide IOEngine when
-        # the store format supports extent submission; False/None forces
-        # the sync reference path; an IOEngine instance is used as-is
-        self._io_engine_opt = io_engine
-        self._stage_engine_opt = stage_engine
         # -- fault domain (docs/robustness.md) --------------------------
         self.fault_injector = None            # chaos: threaded into runtimes
         self.retry_policy = None              # per-task retry (None=default)
@@ -622,8 +615,7 @@ class ColdEngine:
         if ent is None:
             w = {}
             if l.spec.weight_shapes:
-                w = stage_weights(
-                    ref.transform(self.store.read_raw(layer), l.spec))
+                w = stage(ref.transform(self.store.read_raw(layer), l.spec))
             fn = jax.jit(
                 (lambda kern, spec: lambda w_, x_:
                  kern.execute(w_, x_, spec))(ref, l.spec))
@@ -685,33 +677,6 @@ class ColdEngine:
         self._jitted_cache[key] = jitted
         return jitted
 
-    def _resolve_io_engines(self) -> Tuple[Optional[Any], Optional[Any]]:
-        """Resolve the ``io_engine``/``stage_engine`` knobs to instances.
-
-        ``"auto"`` binds the process-wide engines lazily — only when a
-        runtime is actually built, and only when the store format supports
-        extent submission (legacy npy stays on the sync reference path).
-        ``False``/``None`` disables; instances pass through."""
-        io_eng = self._io_engine_opt
-        if io_eng == "auto":
-            io_eng = None
-            if getattr(self.store, "supports_async", False):
-                from repro.ioengine import get_io_engine
-
-                io_eng = get_io_engine()
-        elif not io_eng:
-            io_eng = None
-        st_eng = self._stage_engine_opt
-        if st_eng == "auto":
-            st_eng = None
-            if io_eng is not None:
-                from repro.ioengine import get_stage_engine
-
-                st_eng = get_stage_engine()
-        elif not st_eng:
-            st_eng = None
-        return io_eng, st_eng
-
     def make_runtime(self, *, n_little: int = 3, plan: Optional[Plan] = None,
                      work_stealing: bool = True) -> PipelineRuntime:
         plan = plan or self.plan
@@ -754,7 +719,6 @@ class ColdEngine:
             return self._fallback_execute(
                 name, x, exc, chosen=choice_by_layer[name].kernel)
 
-        io_eng, st_eng = self._resolve_io_engines()
         return PipelineRuntime(
             self.specs, kernels, use_cache, self.store, jitted,
             n_little=n_little, work_stealing=work_stealing,
@@ -762,7 +726,6 @@ class ColdEngine:
             retry=self.retry_policy, deadline_s=self.task_deadline_s,
             fault_injector=self.fault_injector, repair_log=self.repairs,
             fallback_exec=fallback_exec, exec_allowed=exec_allowed,
-            io_engine=io_eng, stage_engine=st_eng,
         )
 
     def _runtime(self, *, n_little: int, work_stealing: bool) -> PipelineRuntime:
@@ -821,9 +784,9 @@ class ColdEngine:
             kern = self._kernel_by_name(l.spec, ch.kernel)
             raw = self.store.read_raw(l.spec.name) if l.spec.weight_shapes else {}
             w = kern.transform(raw, l.spec) if l.spec.weight_shapes else {}
-            # stage_weights, not jnp.asarray: identity transforms hand back
-            # mmap views whose aliasing would leave disk I/O in execute
-            weights[l.spec.name] = stage_weights(w)
+            # stage, not jnp.asarray: identity transforms hand back mmap
+            # views whose aliasing would leave disk I/O in execute
+            weights[l.spec.name] = stage(w)
         best = float("inf")
         for _ in range(repeats):
             t0 = time.perf_counter()

@@ -10,12 +10,10 @@ no thread creation, and an idle worker steals the tail of the prep queue
 with the most remaining preparation time (§3.3, the same
 ``pick_steal_donor`` rule the scheduler's simulator models).
 
-Preparation still ends with an explicit *stage* op (``jax.device_put``):
-weights arrive on device as part of prep, off the critical exec chain. With
-``stage_in_prep=False`` staging is deferred to ``any``-affinity tasks —
-whoever idles first stages layer i+1 while layer i executes (the old
-dedicated "stager" threads are gone); ``prefetch=False`` pins deferred
-staging to the big cores, strictly inline before each execute.
+Every read goes through an ``IOEngine`` (the one the runtime is given,
+else the process-wide ``get_io_engine()``), and preparation ends with the
+layer's *stage* op (``core.staging.stage``) on the same core: weights
+arrive on device as part of prep, off the critical exec chain.
 
 Every op's (start, end) is recorded per job for the benchmark breakdowns;
 trace kinds are ``read`` / ``transform`` / ``stage`` / ``execute``.
@@ -32,13 +30,11 @@ import jax.numpy as jnp
 
 from repro.core.registry import Kernel, LayerSpec
 from repro.core.scheduler import Plan
-from repro.core.staging import (
-    COUNTERS, EXPERT_PREFIX, expert_bytes, stage_weights,
-)
+from repro.core.staging import COUNTERS, EXPERT_PREFIX, expert_bytes, stage
 from repro.executor.graph import OpTrace, compile_plan
 from repro.executor.pool import CorePool, Job, get_core_pool
 from repro.faults import TransientFault
-from repro.ioengine import ReadAbandoned
+from repro.ioengine import ReadAbandoned, get_io_engine
 
 __all__ = ["OpTrace", "PipelineJob", "PipelineRuntime", "RunResult"]
 
@@ -178,8 +174,6 @@ class PipelineRuntime:
         jitted: Dict[str, Callable],      # layer name -> jitted exec fn
         n_little: int,
         work_stealing: bool = True,
-        stage_in_prep: bool = True,
-        prefetch: bool = True,
         prep_costs: Optional[Dict[str, float]] = None,
         pool: Optional[CorePool] = None,
         retry=None,                       # faults.RetryPolicy for the job
@@ -188,9 +182,8 @@ class PipelineRuntime:
         repair_log=None,                  # faults.RepairLog (ladder events)
         fallback_exec: Optional[Callable] = None,  # (layer, x, exc) -> y
         exec_allowed: Optional[Callable[[str], bool]] = None,  # breaker
-        io_engine=None,                   # repro.ioengine.IOEngine (async
-                                          # submit/reap reads; None = sync)
-        stage_engine=None,                # repro.ioengine.StageEngine
+        io_engine=None,                   # repro.ioengine.IOEngine; None =
+                                          # the process-wide engine
     ):
         self.specs = {s.name: s for s in specs}
         self.order = [s.name for s in specs]
@@ -200,8 +193,6 @@ class PipelineRuntime:
         self.jitted = jitted
         self.n_little = n_little
         self.work_stealing = work_stealing
-        self.stage_in_prep = stage_in_prep
-        self.prefetch = prefetch
         self.pool = pool
         self.retry = retry
         self.deadline_s = deadline_s
@@ -209,19 +200,11 @@ class PipelineRuntime:
         self.repair_log = repair_log
         self.fallback_exec = fallback_exec
         self.exec_allowed = exec_allowed
-        # async reads go through the engine only when the store's format
-        # supports extent submission (npy legacy stays sync by design)
-        self.io_engine = (io_engine if io_engine is not None
-                          and getattr(store, "supports_async", False)
-                          else None)
-        self.stage_engine = stage_engine
+        self.io_engine = io_engine
         # per-layer prep-cost estimates drive donor selection when stealing;
         # weight bytes are the fallback proxy when no profile is plumbed in
         self.prep_costs = prep_costs or {
             s.name: float(s.weight_bytes) for s in specs}
-
-    # -- device staging (the prep tail) -------------------------------------
-    _device_put = staticmethod(stage_weights)
 
     def _get_pool(self) -> CorePool:
         if self.pool is None:
@@ -241,77 +224,15 @@ class PipelineRuntime:
                 first.append(n)
         ra(first)
 
-    # -- one preparation op (read [+ transform] + stage) --------------------
-    # kept whole for callers that prep a single layer synchronously (tests,
-    # fallback paths); the task graph uses the finer-grained ops below
-    def _prepare(self, layer: str, weights_out: Dict[str, Any],
-                 traces: List[OpTrace], core: str, t0: float, lock,
-                 staged: Optional[Dict[str, threading.Event]] = None):
-        spec = self.specs[layer]
-        if not spec.weight_shapes:
-            with lock:
-                weights_out[layer] = {}
-            if staged is not None:
-                staged[layer].set()
-            return
-        if self.use_cache.get(layer, False):
-            ts = time.perf_counter()
-            w = self._read_op(layer)
-            te = time.perf_counter()
-            traces.append(OpTrace(layer, "read", core, ts - t0, te - t0))
-        else:
-            ts = time.perf_counter()
-            raw = self.store.read_raw(layer)
-            tm = time.perf_counter()
-            w = self.kernels[layer].transform(raw, spec)
-            te = time.perf_counter()
-            traces.append(OpTrace(layer, "read", core, ts - t0, tm - t0))
-            traces.append(OpTrace(layer, "transform", core, tm - t0, te - t0))
-        if self.stage_in_prep and staged is not None:
-            ts = time.perf_counter()
-            w = self._device_put(w)
-            traces.append(OpTrace(layer, "stage", core, ts - t0,
-                                  time.perf_counter() - t0))
-            with lock:
-                weights_out[layer] = w
-            staged[layer].set()
-        else:
-            with lock:
-                weights_out[layer] = w
+    def _reap_read(self, reads: _AsyncReads, layer: str):
+        """The 'read' task body: reap the layer's pending submission — a
+        cached entry (§3.1.2) or raw weights.
 
-    def _read_op(self, layer: str):
-        """The 'read' task body: cached entry (§3.1.2) or raw weights.
-
-        Degradation ladder, first rung: the cache entry is CRC-audited
-        before it is trusted (``LayerStore.audit_cached`` covers the
-        zero-copy mmap path that lazy verification normally skips). A
-        failing or missing entry is transparently recomputed from raw and
-        the repair is journaled — the request never fails over bit-rot."""
-        spec = self.specs[layer]
-        kern = self.kernels[layer]
-        if self.use_cache.get(layer, False):
-            audit = getattr(self.store, "audit_cached", None)
-            ok = audit(layer, kern.name) if audit is not None else True
-            w = self.store.read_cached(layer, kern.name) if ok else {}
-            if not w:
-                # dropped under the plan's feet (journal recovery, checksum
-                # audit, bit-rot): recompute rather than execute weightless
-                w = kern.transform(self.store.read_raw(layer), spec)
-                if self.repair_log is not None:
-                    self.repair_log.record(
-                        "cache_recompute", layer=layer, kernel=kern.name,
-                        reason=("failed CRC audit" if not ok
-                                else "entry missing/dropped"))
-            return w
-        return self.store.read_raw(layer)
-
-    def _read_op_async(self, reads: _AsyncReads, layer: str):
-        """Async 'read' task body: reap the layer's pending submission.
-
-        Same degradation ladder as ``_read_op`` — the CRC audit runs on
-        the reaped bytes inside the pending read (covering exactly the
-        bytes served), and a dropped/missing cache entry recomputes from
-        raw with the repair journaled."""
+        Degradation ladder, first rung: the CRC audit runs on the reaped
+        bytes inside the pending read (covering exactly the bytes served),
+        and a failing, dropped or missing cache entry is transparently
+        recomputed from raw with the repair journaled — the request never
+        fails over bit-rot."""
         w = reads.wait(layer)
         if self.use_cache.get(layer, False) and not w:
             spec = self.specs[layer]
@@ -321,7 +242,7 @@ class PipelineRuntime:
                 if self.repair_log is not None:
                     self.repair_log.record(
                         "cache_recompute", layer=layer, kernel=kern.name,
-                        reason="entry missing/dropped (async read)")
+                        reason="entry missing/dropped")
         return w
 
     # -- graph compilation + submission -------------------------------------
@@ -361,27 +282,17 @@ class PipelineRuntime:
             + [self.order[i] for i in plan.big_prep]
             + self.order[: 2 * (len(queues) + 1)])
 
-        reads = (_AsyncReads(self, self.io_engine)
-                 if self.io_engine is not None else None)
-        ra_stats: Optional[Dict[str, Any]] = None
-        if reads is not None:
-            # readahead hints route through the engine: the plan's first
-            # layers are submitted NOW, so their bytes are moving before
-            # any worker picks up a read task (the async analogue of the
-            # madvise hint, and counted the same way)
-            seen: set = set()
-            first = [n for n in hint_layers
-                     if not (n in seen or seen.add(n))]
-            reads.prefetch(first)
-            ra_stats = {"mode": "engine", "layers_requested": len(first),
-                        "layers_hinted": reads.prefetched,
-                        "bytes_hinted": reads.prefetch_bytes,
-                        "madvise_available": False}
-        else:
-            self._hint_readahead(hint_layers)
-            st = getattr(self.store, "readahead_stats", None)
-            if st is not None:
-                ra_stats = {"mode": "madvise", **st}
+        reads = _AsyncReads(self, self.io_engine or get_io_engine())
+        # readahead hints route through the engine: the plan's first layers
+        # are submitted NOW, so their bytes are moving before any worker
+        # picks up a read task
+        seen: set = set()
+        first = [n for n in hint_layers if not (n in seen or seen.add(n))]
+        reads.prefetch(first)
+        ra_stats = {"mode": "engine", "layers_requested": len(first),
+                    "layers_hinted": reads.prefetched,
+                    "bytes_hinted": reads.prefetch_bytes,
+                    "madvise_available": False}
 
         fetch_layers = None
         if peer_fetch is not None:
@@ -393,8 +304,6 @@ class PipelineRuntime:
                       for n in self.order},
             use_cache=self.use_cache,
             prep_costs=self.prep_costs,
-            stage_in_prep=self.stage_in_prep,
-            deferred_stage_affinity="any" if self.prefetch else "big",
             fetch_layers=fetch_layers,
         )
         # a layer with a kind of its own names it, with its op type, in
@@ -419,12 +328,11 @@ class PipelineRuntime:
         # layer plus the next (depth-1) layers of its lane, so a little
         # core keeps Plan.read_depth reads in flight instead of one
         succ: Dict[str, List[str]] = {}
-        if reads is not None:
-            seqs = list(graph.lane_queues().values())
-            seqs.append(graph.big_prep_layers())
-            for seq in seqs:
-                for i, n in enumerate(seq):
-                    succ[n] = seq[i + 1:]
+        seqs = list(graph.lane_queues().values())
+        seqs.append(graph.big_prep_layers())
+        for seq in seqs:
+            for i, n in enumerate(seq):
+                succ[n] = seq[i + 1:]
 
         # task fns are VALUE-IDEMPOTENT: every stage writes its own
         # (name, kind) key instead of mutating/popping a shared one, so a
@@ -433,18 +341,12 @@ class PipelineRuntime:
         # corrupt the chain. (Intermediates are held until the job ends;
         # the pool-retry safety is worth the transient footprint.)
         def read_fn(name, depth=1):
-            if reads is None:
-                def fn():
-                    pending[(name, "read")] = self._read_op(name)
-                return fn
-
             ahead = succ.get(name, [])[:max(0, depth - 1)]
 
             def fn():
                 reads.prefetch(ahead)   # keep the lane at planned depth
                 try:
-                    pending[(name, "read")] = self._read_op_async(reads,
-                                                                  name)
+                    pending[(name, "read")] = self._reap_read(reads, name)
                 except ReadAbandoned:
                     # warm-state fetch won this layer mid-read: the chain's
                     # later tasks are already cancelled — bail, freeing the
@@ -463,10 +365,7 @@ class PipelineRuntime:
                 src = pending.get((name, "xf"), None)
                 if src is None:
                     src = pending[(name, "read")]
-                if self.stage_engine is not None:
-                    w = self.stage_engine.stage(src)
-                else:
-                    w = self._device_put(src)
+                w = stage(src)
                 COUNTERS["expert_stage_bytes"] += expert_bytes(w)
                 with lock:
                     won = name not in weights
@@ -496,10 +395,7 @@ class PipelineRuntime:
             with lock:
                 lost = name in weights           # local chain already won
             if not lost:
-                if self.stage_engine is not None:
-                    staged = self.stage_engine.stage(w)
-                else:
-                    staged = self._device_put(w)
+                staged = stage(w)
                 with lock:
                     lost = name in weights       # ...or won while we staged
                     if not lost:
@@ -517,8 +413,7 @@ class PipelineRuntime:
             if job is not None:
                 self._get_pool().cancel_tasks(
                     job, chain_tids.get(name, ()), reason="race_fetch_won")
-            if reads is not None:
-                reads.abort(name)
+            reads.abort(name)
 
         def fetch_failed(e):
             job = jobref[0]
@@ -593,10 +488,9 @@ class PipelineRuntime:
             retry=self.retry, deadline_s=self.deadline_s,
             job_deadline_s=job_deadline_s)
         jobref[0] = job
-        if reads is not None:
-            # engine buffers recycle only once no retry/zombie can still
-            # reap them — i.e. when the job is finished for good
-            job.add_done_callback(lambda _j: reads.close())
+        # engine buffers recycle only once no retry/zombie can still reap
+        # them — i.e. when the job is finished for good
+        job.add_done_callback(lambda _j: reads.close())
         if peer_fetch is not None:
             def _end_race(j):
                 peer_fetch.close()
@@ -637,7 +531,7 @@ class PipelineRuntime:
             traces.append(OpTrace(name, "transform", "big", ts - t0, time.perf_counter() - t0))
         for name in self.order:           # stage all (host -> device)
             ts = time.perf_counter()
-            weights[name] = self._device_put(weights[name])
+            weights[name] = stage(weights[name])
             traces.append(OpTrace(name, "stage", "big", ts - t0, time.perf_counter() - t0))
         y = x
         for name in self.order:           # execute all (device-resident weights)

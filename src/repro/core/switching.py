@@ -21,7 +21,7 @@ import jax.numpy as jnp
 
 from repro.core.engine import ColdEngine
 from repro.core.pipeline import RunResult, OpTrace
-from repro.core.staging import stage_weights
+from repro.core.staging import stage
 from repro.executor.graph import TaskGraph
 from repro.executor.pool import Job
 
@@ -67,12 +67,11 @@ class ContinuousSession:
                 raw = eng.store.read_raw(l.spec.name)
                 w = kern.transform(raw, l.spec)
                 with self._lock:
-                    # stage_weights (not bare jnp.asarray): identity
-                    # transforms hand back read-only mmap views, which CPU
-                    # XLA would alias — leaving their disk I/O to fault in
-                    # during execute
-                    self.warm_weights[l.spec.name] = (
-                        wc.kernel, stage_weights(w))
+                    # stage (not bare jnp.asarray): identity transforms
+                    # hand back read-only mmap views, which CPU XLA would
+                    # alias — leaving their disk I/O to fault in during
+                    # execute
+                    self.warm_weights[l.spec.name] = (wc.kernel, stage(w))
             return fn
 
         g = TaskGraph()
@@ -115,7 +114,7 @@ class ContinuousSession:
                 else:
                     w = kern.transform(eng.store.read_raw(name), l.spec) \
                         if l.spec.weight_shapes else {}
-                y = jitted_cold[name](stage_weights(w), y)
+                y = jitted_cold[name](stage(w), y)
             jax.block_until_ready(y)
             traces.append(OpTrace(name, "execute", "big",
                                   ts - t0, time.perf_counter() - t0))

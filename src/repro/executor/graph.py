@@ -50,8 +50,8 @@ from repro.core.scheduler import Plan, simulate
 PREP_KINDS = ("read", "transform", "stage")
 
 #: affinity tags: ``big`` (big-core workers), ``little`` (the lane's little
-#: worker, stealable), ``any`` (whoever idles first — deferred staging,
-#: background packing)
+#: worker, stealable), ``any`` (whoever idles first — warm-state fetch
+#: markers, background packing)
 AFFINITIES = ("big", "little", "any")
 
 
@@ -303,8 +303,6 @@ def compile_plan(
     weighted: Dict[str, bool],
     use_cache: Dict[str, bool],
     prep_costs: Optional[Dict[str, float]] = None,
-    stage_in_prep: bool = True,
-    deferred_stage_affinity: str = "any",
     read_depth: Optional[int] = None,
     fetch_layers: Optional[Sequence[str]] = None,
 ) -> TaskGraph:
@@ -312,11 +310,8 @@ def compile_plan(
 
     ``weighted`` marks layers with on-disk weights (weightless/stateless
     units get only an ``execute`` task, like the runtime always treated
-    them). With ``stage_in_prep`` the ``stage`` op is the tail of the prep
-    chain on the same core; otherwise it is emitted with
-    ``deferred_stage_affinity`` (``any`` = prefetch: whoever idles first,
-    including the big core right before the layer's execute; ``big`` =
-    strictly inline on the big cores).
+    them). The ``stage`` op is the tail of the prep chain, on the same
+    core.
 
     ``read_depth`` (default: the plan's) stamps every read task with the
     I/O queue depth the async engine should sustain — the runtime's read
@@ -350,11 +345,7 @@ def compile_plan(
         if not use_cache.get(name, False):
             prev = g.add(name, "transform", affinity=aff, lane=lane,
                          deps=(prev.tid,))
-        if stage_in_prep:
-            g.add(name, "stage", affinity=aff, lane=lane, deps=(prev.tid,))
-        else:
-            g.add(name, "stage", affinity=deferred_stage_affinity,
-                  lane=None, deps=(prev.tid,))
+        g.add(name, "stage", affinity=aff, lane=lane, deps=(prev.tid,))
 
     # big-core preps first (tid order is the big worker's priority order:
     # the plan's big preps run before the exec chain, as Algorithm 1 lays

@@ -17,9 +17,8 @@ Scheduling rules (mirroring the plan simulator, §3.3):
     whose whole prep chain is retargeted to the thief's lane;
   * big workers run ``big``-affinity tasks in tid order (the plan's big
     preps first, then the exec chain as its deps release);
-  * ``any``-affinity tasks (deferred staging, background packing) go to
-    whoever idles first — an idle little core prefetch-stages layer i+1
-    while the big core executes layer i, without a dedicated stager thread.
+  * ``any``-affinity tasks (warm-state fetch markers, background packing)
+    go to whoever idles first, without a dedicated thread of their own.
 
 Fault domain (``repro.faults``):
 

@@ -43,6 +43,7 @@ from repro.core.scheduler import transfer_estimate
 from repro.executor.pool import CorePool, get_core_pool
 from repro.executor.warmstate import PACKED_LAYER, PeerFetcher
 from repro.faults import DeadlineExceeded, ModelQuarantined
+from repro.ioengine import get_io_engine
 
 
 def _weights_nbytes(weights: Optional[Dict[str, Any]]) -> int:
@@ -186,7 +187,6 @@ class ColdServer:
         share_profile_db: bool = True,
         quarantine_base_s: float = 0.5,
         quarantine_max_s: float = 30.0,
-        io_engine: Any = "auto",
         max_read_bytes_in_flight: Optional[int] = None,
         idle_compaction: bool = True,
         idle_compaction_min_interval_s: float = 0.25,
@@ -248,16 +248,10 @@ class ColdServer:
         self._outstanding = 0
         self._drain_cv = threading.Condition(self._lock)
         self._served: Dict[str, int] = {}   # model -> completed requests
-        # shared async I/O engine: byte-budget admission + idle compaction.
-        # "auto" binds the process-wide engine; False/None runs without one
-        # (engines fall back to their own resolution / the sync path).
-        if io_engine == "auto":
-            from repro.ioengine import get_io_engine
-
-            self.io_engine = get_io_engine()
-        else:
-            self.io_engine = io_engine or None
-        if self.io_engine is not None and max_read_bytes_in_flight is not None:
+        # the process-wide async I/O engine, which every engine's runtime
+        # reads through: byte-budget admission + idle compaction
+        self.io_engine = get_io_engine()
+        if max_read_bytes_in_flight is not None:
             self.io_engine.set_max_bytes_in_flight(max_read_bytes_in_flight)
         # idle-tick incremental compaction: when the engine's read queue
         # drains, give ONE store (round-robin) one bounded background
@@ -268,7 +262,7 @@ class ColdServer:
         self._idle_rr = 0
         self._idle_busy = False
         self._idle_compaction = bool(idle_compaction)
-        if self.io_engine is not None and idle_compaction:
+        if idle_compaction:
             self.io_engine.add_idle_callback(self._on_io_idle)
 
     # -- model management ---------------------------------------------------
@@ -279,8 +273,6 @@ class ColdServer:
         engine_kw.setdefault("pool", self.pool)
         if self.profile_db is not None:
             engine_kw.setdefault("profile_db", self.profile_db)
-        if self.io_engine is not None:
-            engine_kw.setdefault("io_engine", self.io_engine)
         eng = ColdEngine(layers, self.root / name, **engine_kw)
         self.engines[name] = eng
         return eng
@@ -447,8 +439,7 @@ class ColdServer:
         # the engine's idle edge usually lands while this job's transform/
         # stage tail is still running (active_preps > 0, tick skipped) —
         # re-check when the prep phase itself ends
-        if self.io_engine is not None and self._idle_compaction \
-                and self.io_engine.reads_in_flight() == 0:
+        if self._idle_compaction and self.io_engine.reads_in_flight() == 0:
             self._on_io_idle()
 
     # -- idle-tick incremental compaction ------------------------------------
@@ -572,8 +563,7 @@ class ColdServer:
             except Exception:
                 pass
         snap["local_read_bytes"] = total_read
-        if self.io_engine is not None:
-            snap["io_engine"] = self.io_engine.snapshot()
+        snap["io_engine"] = self.io_engine.snapshot()
         return snap
 
     def run(self, name: str, x) -> RunResult:

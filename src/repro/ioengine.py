@@ -24,8 +24,9 @@ backends, selected at probe time exactly like the CRC-32C backends
 Reads land in buffers from a :class:`PinnedBufferPool` — pre-registered
 anonymous slabs, ``mlock``-pinned where the RLIMIT allows (recorded, not
 required) and recycled by size class.  Reaped views are returned
-**read-only** so the existing staging contract applies unchanged:
-``stage_weights`` materializes read-only views into anonymous memory
+**read-only** so the staging contract applies unchanged:
+``repro.core.staging.stage`` copies read-only views (into anonymous memory
+on CPU, into a pinned bounce buffer of its own pool on an accelerator)
 before ``jax.device_put``, which is exactly what makes buffer recycling
 safe — a recycled slab can never alias a device-resident weight.  Pool
 buffers are released back per *job* (the executor holds task values until
@@ -44,14 +45,6 @@ sites ``ioengine.submit`` and ``ioengine.reap`` (typed ``ReadFault``,
 bounded retries by the executor's existing policy), alongside the
 store-level ``store.read_raw``/``store.read_cached`` sites, so the chaos
 and crash gates run unchanged with the engine active.
-
-Staging has the same split: :class:`StageEngine` routes the ``stage`` op
-through a dedicated DMA queue thread on accelerators (host->device copies
-issue from pinned bounce buffers, serialized so they never contend with
-the exec chain's own transfers) and falls back to the inline host path
-(``stage_weights``) on CPU hosts, where ``jax.device_put`` may zero-copy
-alias host memory and a bounce buffer would be aliasing hazard, not a
-win.  ``REPRO_STAGE_ENGINE`` overrides.
 """
 from __future__ import annotations
 
@@ -68,12 +61,11 @@ from typing import Any, Callable, Dict, List, Optional, Tuple
 
 import numpy as np
 
-from repro.faults import ReadFault, StageFault, classify
+from repro.faults import ReadFault, classify
 
 __all__ = [
     "IOEngine", "ReadTicket", "ReadAbandoned", "TransferCharge",
-    "PinnedBufferPool", "PinnedBuffer", "StageEngine", "get_io_engine",
-    "reset_io_engine", "get_stage_engine", "reset_stage_engine",
+    "PinnedBufferPool", "PinnedBuffer", "get_io_engine", "reset_io_engine",
     "available_backends",
 ]
 
@@ -84,7 +76,6 @@ class ReadAbandoned(Exception):
     caller bails out of the chain instead of retrying."""
 
 ENV_ENGINE = "REPRO_IO_ENGINE"
-ENV_STAGE = "REPRO_STAGE_ENGINE"
 
 # ---------------------------------------------------------------------------
 # pinned buffer pool
@@ -984,114 +975,6 @@ class IOEngine:
 
 
 # ---------------------------------------------------------------------------
-# stage engine (host | dma)
-# ---------------------------------------------------------------------------
-
-class StageEngine:
-    """Backend-pluggable ``stage`` op.
-
-    host  inline ``stage_weights`` — the reference path, and the only
-          safe one on CPU hosts where ``jax.device_put`` may zero-copy
-          alias writable host buffers (a pinned bounce buffer would be
-          recycled under a live alias).
-    dma   dedicated DMA-queue thread: weights are copied into a pinned
-          bounce buffer and ``device_put`` issues from it, serialized so
-          staging transfers never contend with the exec chain's own
-          copies.  Auto-selected only when the default jax device is a
-          real accelerator; ``REPRO_STAGE_ENGINE`` overrides.
-    """
-
-    def __init__(self, backend: Optional[str] = None,
-                 pool: Optional[PinnedBufferPool] = None):
-        forced = backend or os.environ.get(ENV_STAGE) or None
-        if forced is None:
-            forced = "dma" if self._accelerator_present() else "host"
-        if forced not in ("host", "dma"):
-            raise ValueError(f"unknown stage engine {forced!r} "
-                             f"(choices: ['dma', 'host'])")
-        self.name = forced
-        self.pool = pool or PinnedBufferPool()
-        self.stats = {"staged": 0, "bytes_staged": 0, "dma_queue_peak": 0}
-        self._q: Optional["queue.Queue"] = None
-        self._thread: Optional[threading.Thread] = None
-        if self.name == "dma":
-            self._q = queue.Queue()
-            self._thread = threading.Thread(
-                target=self._dma_loop, name="repro-stage-dma", daemon=True)
-            self._thread.start()
-
-    @staticmethod
-    def _accelerator_present() -> bool:
-        # no try/except: a backend that fails to initialise must surface
-        # here, not quietly select the host path
-        import jax
-        return jax.default_backend() != "cpu"
-
-    # -- host path ----------------------------------------------------------
-    def _stage_host(self, w: Dict[str, Any]) -> Dict[str, Any]:
-        from repro.core.staging import stage_weights
-        return stage_weights(w)
-
-    # -- dma path -----------------------------------------------------------
-    def _dma_loop(self) -> None:
-        import jax
-        while True:
-            item = self._q.get()
-            if item is None:
-                return
-            w, out, done = item
-            try:
-                staged = {}
-                for k, v in w.items():
-                    arr = np.asarray(v)
-                    buf = self.pool.acquire(arr.nbytes)
-                    try:
-                        bounce = buf.arr[:arr.nbytes].view(arr.dtype).reshape(
-                            arr.shape)
-                        np.copyto(bounce, arr)
-                        # device_put copies across the bus on accelerators;
-                        # the bounce buffer is free to recycle right after
-                        staged[k] = jax.device_put(bounce)
-                        jax.block_until_ready(staged[k])
-                    finally:
-                        buf.release()
-                out["staged"] = staged
-            except BaseException as e:
-                out["error"] = e
-            finally:
-                done.set()
-
-    def stage(self, w: Dict[str, Any]) -> Dict[str, Any]:
-        if not w:
-            return {}
-        if self.name == "host" or self._q is None:
-            staged = self._stage_host(w)
-        else:
-            out: Dict[str, Any] = {}
-            done = threading.Event()
-            self._q.put((w, out, done))
-            self.stats["dma_queue_peak"] = max(
-                self.stats["dma_queue_peak"], self._q.qsize())
-            done.wait()
-            if "error" in out:
-                err = out["error"]
-                if isinstance(err, BaseException):
-                    raise StageFault(f"dma stage failed: {err}") from err
-            staged = out["staged"]
-        self.stats["staged"] += 1
-        self.stats["bytes_staged"] += sum(
-            int(getattr(v, "nbytes", 0)) for v in w.values())
-        return staged
-
-    def close(self) -> None:
-        if self._q is not None and self._thread is not None:
-            self._q.put(None)
-            self._thread.join(timeout=5.0)
-            self._thread = None
-        self.pool.close()
-
-
-# ---------------------------------------------------------------------------
 # process-wide singleton (mirrors executor.pool.get_core_pool)
 # ---------------------------------------------------------------------------
 
@@ -1116,21 +999,3 @@ def reset_io_engine() -> None:
     if eng is not None:
         eng.close()
 
-
-_stage_engine: Optional[StageEngine] = None
-
-
-def get_stage_engine(**kw) -> StageEngine:
-    global _stage_engine
-    with _engine_lock:
-        if _stage_engine is None:
-            _stage_engine = StageEngine(**kw)
-        return _stage_engine
-
-
-def reset_stage_engine() -> None:
-    global _stage_engine
-    with _engine_lock:
-        eng, _stage_engine = _stage_engine, None
-    if eng is not None:
-        eng.close()

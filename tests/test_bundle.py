@@ -2,8 +2,7 @@
 
 Covers the cold-path I/O overhaul: bundle round-trips across dtypes
 (f32/bf16/int8), 64-byte segment alignment, mmap-view immutability,
-bundle-vs-legacy LayerStore equivalence on a cnn_zoo model, and the
-pipeline's 'stage' ops (weights arrive on device during prep — no
+LayerStore bundle round-trips, and the pipeline's 'stage' ops (weights arrive on device during prep — no
 host->device conversion on the exec chain).
 """
 import numpy as np
@@ -71,33 +70,6 @@ def test_bundle_rejects_bad_magic(tmp_path):
     p.write_bytes(b"NOPE" + b"\0" * 64)
     with pytest.raises(ValueError):
         read_bundle(p)
-
-
-def test_layerstore_bundle_matches_legacy_npy(tmp_path):
-    """Bundle reads == legacy per-tensor reads on a cnn_zoo model."""
-    from repro.models.cnn import build_cnn
-
-    layers, _ = build_cnn("mobilenet", image=24, width=0.35)
-    s_bun = LayerStore(tmp_path / "bundle", fmt="bundle")
-    s_npy = LayerStore(tmp_path / "npy", fmt="npy")
-    for l in layers:
-        if not l.weights:
-            continue
-        s_bun.write_raw(l.spec.name, l.weights)
-        s_npy.write_raw(l.spec.name, l.weights)
-    for l in layers:
-        if not l.weights:
-            continue
-        for mmap in (False, True):
-            b = s_bun.read_raw(l.spec.name, mmap=mmap)
-            n = s_npy.read_raw(l.spec.name)
-            assert set(b) == set(n)
-            for k in b:
-                assert b[k].dtype == n[k].dtype
-                np.testing.assert_array_equal(np.asarray(b[k]), n[k])
-    # weightless layers read back as {} in both formats
-    assert s_bun.read_raw("stateless_layer") == {}
-    assert s_npy.read_raw("stateless_layer") == {}
 
 
 def test_layerstore_dotted_layer_names_do_not_collide(tmp_path):

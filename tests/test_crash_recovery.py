@@ -461,12 +461,31 @@ def test_rewrite_audits_extents_instead_of_restamping(tmp_path):
         st4.read_raw("b")  # flush must refuse to copy rotten raw forward
 
 
+def _prep_one_layer(rt):
+    """Run the runtime's one-layer plan through its task graph (reads
+    through a sync I/O engine) and return the layer's staged weights, as
+    ``graph_hook`` sees them."""
+    from repro.core.scheduler import Choice, Plan
+    from repro.ioengine import IOEngine
+
+    seen = {}
+
+    def hook(graph, weights, lock):
+        seen["weights"] = weights
+
+    plan = Plan([Choice(rt.kernels["l"].name, True)], big_prep=[0],
+                little_queues=[], est_makespan=0.0)
+    rt.io_engine = IOEngine(backend="sync")
+    try:
+        rt.submit(np.zeros(1, np.float32), plan, graph_hook=hook).result(30)
+    finally:
+        rt.io_engine.close()
+    return seen["weights"]
+
+
 def test_pipeline_prep_falls_back_when_cache_dropped(tmp_path):
     """A use_cache layer whose entry was dropped (recovery/audit) must be
     re-derived from raw by the runtime, never executed with no weights."""
-    import threading
-    import time as time_mod
-
     from repro.core.pipeline import PipelineRuntime
     from repro.core.registry import LayerSpec
 
@@ -485,9 +504,7 @@ def test_pipeline_prep_falls_back_when_cache_dropped(tmp_path):
                      weight_shapes={"w": (8,)})
     rt = PipelineRuntime([spec], {"l": Kern()}, {"l": True}, st,
                          {"l": lambda w, x: x}, n_little=1)
-    weights, traces = {}, []
-    rt._prepare("l", weights, traces, "little", time_mod.perf_counter(),
-                threading.Lock())
+    weights = _prep_one_layer(rt)
     np.testing.assert_array_equal(np.asarray(weights["l"]["w"]),
                                   raw["w"] * 2)
 
@@ -825,9 +842,6 @@ def test_pipeline_prep_rederives_quantized_entry_after_drop(tmp_path):
     """Runtime rung of the same ladder: a use_cache layer whose quantized
     entry was dropped must be re-derived from raw by the pipeline runtime
     — bit-identical companions, never empty weights."""
-    import threading
-    import time as time_mod
-
     from repro import quant
     from repro.core.pipeline import PipelineRuntime
     from repro.core.registry import LayerSpec, LinearInt4
@@ -842,9 +856,7 @@ def test_pipeline_prep_rederives_quantized_entry_after_drop(tmp_path):
                      weight_shapes={"w": raw["w"].shape})
     rt = PipelineRuntime([spec], {"l": kern}, {"l": True}, st,
                          {"l": lambda w, x: x}, n_little=1)
-    weights, traces = {}, []
-    rt._prepare("l", weights, traces, "little", time_mod.perf_counter(),
-                threading.Lock())
+    weights = _prep_one_layer(rt)
     want = kern.transform(dict(raw), spec)
     assert set(weights["l"]) == set(want)
     for k in want:

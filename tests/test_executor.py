@@ -2,9 +2,8 @@
 
 Covers the PR-5 invariants: plan ↔ task-graph equivalence (one shared
 representation), zero per-run thread creation on the steady path, work
-stealing under a persistent pool across back-to-back runs, deferred-staging
-traces landing before results, and two models cold-starting concurrently
-without cross-talk in traces or weights.
+stealing under a persistent pool across back-to-back runs, and two models
+cold-starting concurrently without cross-talk in traces or weights.
 """
 import time
 
@@ -211,7 +210,7 @@ def test_empty_and_unbound_graphs(pool):
 
 
 # ---------------------------------------------------------------------------
-# engine-level: steady path + deferred staging through the real pipeline
+# engine-level: the steady path through the real pipeline
 # ---------------------------------------------------------------------------
 @pytest.fixture(scope="module")
 def tiny_engine(tmp_path_factory):
@@ -237,25 +236,6 @@ def test_cold_runs_create_no_threads_after_warmup(tiny_engine):
     # runtime object is reused, not rebuilt per call
     assert eng._runtime(n_little=2, work_stealing=True) is \
         eng._runtime(n_little=2, work_stealing=True)
-
-
-def test_deferred_stage_traces_complete_before_result(tiny_engine):
-    """stage_in_prep=False: 'any'-affinity staging (the old stager threads)
-    must land every trace before the job completes, exactly once per
-    weighted layer, and strictly before the layer's execute."""
-    eng, x = tiny_engine
-    rt = eng.make_runtime(n_little=2)
-    rt.stage_in_prep = False
-    res = rt.run(np.asarray(x, np.float32), eng.plan)
-    n = len(res.traces)
-    time.sleep(0.05)
-    assert len(res.traces) == n
-    weighted = {l.spec.name for l in eng.layers if l.spec.weight_shapes}
-    stage = [t for t in res.traces if t.kind == "stage"]
-    assert {t.layer for t in stage} == weighted and len(stage) == len(weighted)
-    exec_start = {t.layer: t.start for t in res.traces if t.kind == "execute"}
-    for t in stage:
-        assert t.end <= exec_start[t.layer] + 1e-9
 
 
 def test_graph_hook_extends_job(tiny_engine):

@@ -14,18 +14,16 @@ import repro.ioengine as iomod
 from repro.checkpoint import LayerStore
 from repro.faults import FaultInjector, ReadFault, RetryPolicy
 from repro.ioengine import (
-    IOEngine, POOL_BYTES, PinnedBufferPool, StageEngine, available_backends,
-    get_io_engine, reset_io_engine, reset_stage_engine,
+    IOEngine, POOL_BYTES, PinnedBufferPool, available_backends,
+    get_io_engine, reset_io_engine,
 )
 
 
 @pytest.fixture(autouse=True)
 def _fresh_singletons():
     reset_io_engine()
-    reset_stage_engine()
     yield
     reset_io_engine()
-    reset_stage_engine()
 
 
 def _write_file(path, nbytes, seed=7):
@@ -289,7 +287,6 @@ def _store_with_layers(tmp_path, fmt):
 @pytest.mark.parametrize("backend", available_backends())
 def test_submit_read_raw_matches_sync(tmp_path, fmt, backend):
     store, want = _store_with_layers(tmp_path, fmt)
-    assert store.supports_async
     eng = IOEngine(backend=backend)
     try:
         handles = {n: store.submit_read_raw(eng, n) for n in want}
@@ -301,18 +298,6 @@ def test_submit_read_raw_matches_sync(tmp_path, fmt, backend):
     finally:
         eng.close()
         store.close()
-
-
-def test_npy_store_stays_sync(tmp_path):
-    store, want = _store_with_layers(tmp_path, "npy")
-    assert not store.supports_async
-    eng = IOEngine(backend="sync")
-    try:
-        h = store.submit_read_raw(eng, "l0")   # immediate-read shim
-        got = h.wait()
-        assert np.array_equal(np.asarray(got["w"]), want["l0"]["w"])
-    finally:
-        eng.close()
 
 
 def test_async_corrupt_cache_extent_drops_and_reports(tmp_path):
@@ -559,28 +544,83 @@ def test_run_result_carries_readahead_stats(tmp_path):
 
 
 # ---------------------------------------------------------------------------
-# stage engine
+# staging routes (core.staging.stage)
 # ---------------------------------------------------------------------------
 def test_stage_engine_host_matches_stage_weights():
-    from repro.core.staging import stage_weights
+    """On CPU, ``stage`` takes the inline route: ``stage_weights``."""
+    from repro.core import staging
 
     w = {"a": np.arange(16, dtype=np.float32).reshape(4, 4)}
-    se = StageEngine(backend="host")
-    got = se.stage(w)
-    ref = stage_weights(w)
+    got = staging.stage(w)
+    ref = staging.stage_weights(w)
     assert np.array_equal(np.asarray(got["a"]), np.asarray(ref["a"]))
-    assert se.stats["staged"] == 1
-    se.close()
+    assert staging.route_name() == "inline"
+    assert staging.stage({}) == {}
 
 
 def test_stage_engine_stages_readonly_views():
-    se = StageEngine(backend="host")
+    from repro.core.staging import stage
+
     a = np.arange(16, dtype=np.float32)
     a.flags.writeable = False   # what ReadTicket.wait hands back
-    got = se.stage({"a": a})
+    got = stage({"a": a})
     assert np.array_equal(np.asarray(got["a"]),
                           np.arange(16, dtype=np.float32))
-    se.close()
+
+
+def test_stage_dma_route_bounce_reuse_and_fault(monkeypatch):
+    """The accelerator's route, driven on CPU: the real ``device_put`` may
+    alias the bounce buffer here, so a copying put stands in for the bus.
+    Back-to-back stages of two dicts come back bit-identical after the
+    bounce buffer was reused; a failing put comes back as ``StageFault``;
+    the route is picked from the backend JAX reports."""
+    import jax
+
+    from repro.core import staging
+    from repro.faults import StageFault
+
+    real_put = jax.device_put
+    monkeypatch.setattr(jax, "device_put",
+                        lambda v: real_put(np.array(v, copy=True)))
+    rng = np.random.default_rng(5)
+    a = {"w": rng.standard_normal((128, 128)).astype(np.float32)}
+    b = {"w": rng.standard_normal((128, 128)).astype(np.float32),
+         "q": rng.integers(-128, 128, (64, 32)).astype(np.int8)}
+    for src in (a, b):
+        for v in src.values():
+            v.flags.writeable = False   # reaped views are read-only
+    pool = PinnedBufferPool(max_bytes=1 << 20, pin=False)
+    dma = staging._DmaQueue(pool)
+    try:
+        got_a = dma(a)
+        got_b = dma(b)
+        assert pool.stats["reuses"] >= 1, pool.stats
+        for src, got in ((a, got_a), (b, got_b)):
+            assert set(got) == set(src)
+            for k, v in src.items():
+                assert np.asarray(got[k]).dtype == v.dtype
+                np.testing.assert_array_equal(np.asarray(got[k]), v)
+
+        def bad_put(v):
+            raise RuntimeError("bus error")
+
+        monkeypatch.setattr(jax, "device_put", bad_put)
+        with pytest.raises(StageFault, match="bus error"):
+            dma(a)
+    finally:
+        dma.close()
+
+    monkeypatch.setattr(staging, "_route", None)
+    monkeypatch.setattr(jax, "default_backend", lambda: "cpu")
+    assert staging._pick_route() is staging.stage_weights
+    monkeypatch.setattr(staging, "_route", None)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    route = staging._pick_route()
+    try:
+        assert isinstance(route, staging._DmaQueue)
+        assert staging.route_name() == "dma"
+    finally:
+        route.close()
 
 
 # ---------------------------------------------------------------------------

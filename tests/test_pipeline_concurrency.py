@@ -1,9 +1,9 @@
 """Pipelined-runtime concurrency invariants.
 
-The prefetch "stager" threads are joined before ``RunResult`` is built, so
-every trace (including theirs) is complete and stable the moment ``run``
-returns; staging is idempotent — each layer is staged exactly once even
-under work stealing + prefetch races; and the sequential baseline's read
+Every task is joined before ``RunResult`` is built, so every trace is
+complete and stable the moment ``run`` returns; staging is idempotent —
+each layer is staged exactly once even under work stealing and read
+prefetch across runs; and the sequential baseline's read
 ops pay the real disk cost (``mmap=False``) instead of deferring it into
 transform/stage through a lazy mmap view.
 """
@@ -25,13 +25,12 @@ def tiny_engine(tmp_path_factory):
 
 
 def test_each_layer_staged_exactly_once_across_runs(tiny_engine):
-    """Work stealing + i+1 prefetch + deferred staging must never produce a
-    duplicate (or missing) 'stage' op for a layer, run after run."""
+    """Work stealing + read prefetch must never produce a duplicate (or
+    missing) 'stage' op for a layer, run after run."""
     eng, x = tiny_engine
     weighted = {l.spec.name for l in eng.layers if l.spec.weight_shapes}
     for _ in range(5):
         rt = eng.make_runtime(n_little=2)
-        rt.stage_in_prep = False  # force the deferred/prefetch staging path
         res = rt.run(np.asarray(x, dtype=np.float32), eng.plan)
         counts = {}
         for t in res.traces:
@@ -41,16 +40,15 @@ def test_each_layer_staged_exactly_once_across_runs(tiny_engine):
 
 
 def test_traces_complete_when_run_returns(tiny_engine):
-    """Stager threads are joined before RunResult is constructed: no trace
-    may be appended after ``run`` returns, and every op kind is fully
+    """Every task is joined before RunResult is constructed: no trace may
+    be appended after ``run`` returns, and every op kind is fully
     accounted for."""
     eng, x = tiny_engine
     rt = eng.make_runtime(n_little=2)
-    rt.stage_in_prep = False
     res = rt.run(np.asarray(x, dtype=np.float32), eng.plan)
     n = len(res.traces)
     time.sleep(0.05)
-    assert len(res.traces) == n, "a stager appended a trace post-return"
+    assert len(res.traces) == n, "a task appended a trace post-return"
     weighted = {l.spec.name for l in eng.layers if l.spec.weight_shapes}
     by_kind = {}
     for t in res.traces:

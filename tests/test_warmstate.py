@@ -3,6 +3,7 @@ fallback, the fetch-vs-disk race (bit-identity + journaling), chaos at
 the fetch sites, memory-pressure refusal, and the abortable paced read
 that keeps a race-losing read from sleeping out the emulated disk."""
 import os
+import shutil
 import socket
 import tempfile
 import threading
@@ -18,15 +19,29 @@ from repro.faults import FaultInjector, FetchFault, TransientFault
 from repro.models.cnn import build_cnn
 
 
-def _mk_server(root, **kw):
+def _mk_server(root, donor=None, **kw):
     """One ColdServer with 'mnet' registered + decided on the measured
     super-bundle store. build_cnn is seed-deterministic, so every server
-    built this way holds bit-identical weights."""
+    built this way holds bit-identical weights. A server built against a
+    ``donor`` starts from the donor's profile DB, and no server measures
+    read interference, so every server plans from the same numbers: the
+    same kernels, hence bit-identical outputs."""
     layers, x = build_cnn("mobilenet", image=16, width=0.25)
+    if donor is not None:
+        os.makedirs(root, exist_ok=True)
+        shutil.copyfile(donor.root / "profile_db.json",
+                        os.path.join(root, "profile_db.json"))
     srv = ColdServer(root, n_little=2, max_concurrent_preps=2, **kw)
     srv.add_model("mnet", layers, store_fmt="super")
-    srv.decide("mnet", x, n_little=2)
+    srv.decide("mnet", x, n_little=2, calibrate_interference=False)
+    if donor is not None:
+        assert _kernels(srv) == _kernels(donor), \
+            "the two servers planned different kernels"
     return srv, x
+
+
+def _kernels(srv):
+    return [c.kernel for c in srv.engines["mnet"].plan.choices]
 
 
 @pytest.fixture(scope="module")
@@ -117,8 +132,8 @@ def test_fetch_unknown_model_raises_typed():
 # the race, end to end (two servers, one process)
 # ---------------------------------------------------------------------------
 def test_race_bit_identical_and_journaled(donor, tmp_path):
-    _, warm, _, ref = donor
-    srv_b, x = _mk_server(tmp_path)
+    srv_a, warm, _, ref = donor
+    srv_b, x = _mk_server(tmp_path, donor=srv_a)
     ticket = srv_b.cold_start("mnet", x, peers=_peers(warm))
     out = np.asarray(ticket.result().output)
     np.testing.assert_array_equal(out, ref)
@@ -135,8 +150,8 @@ def test_race_bit_identical_and_journaled(donor, tmp_path):
 def test_slow_peer_declined(donor, tmp_path):
     """The cost model declines the race when the transfer estimate loses
     to the local plan: no fetcher is built, no session hits the donor."""
-    _, warm, _, ref = donor
-    srv_b, x = _mk_server(tmp_path)
+    srv_a, warm, _, ref = donor
+    srv_b, x = _mk_server(tmp_path, donor=srv_a)
     sessions = warm.stats["sessions"]
     slow = [{"host": warm.host, "port": warm.port,
              "resident_bytes": 1 << 40, "link_bytes_per_s": 1e3}]
@@ -152,8 +167,8 @@ def test_crc_corruption_falls_back_bit_identical(donor, tmp_path):
     """A corrupted chunk must surface as a typed integrity failure on the
     fetching side and NEVER into the weights: the cold start falls back to
     its local chains and still produces the bit-identical output."""
-    _, warm, _, ref = donor
-    srv_b, x = _mk_server(tmp_path)
+    srv_a, warm, _, ref = donor
+    srv_b, x = _mk_server(tmp_path, donor=srv_a)
     warm.corrupt_chunks = 2
     try:
         ticket = srv_b.cold_start("mnet", x, peers=_peers(warm))
@@ -170,8 +185,8 @@ def test_injected_fetch_fault_falls_back_no_leaks(donor, tmp_path):
     """Chaos at the warmstate.fetch site: every delivery faults, the
     stream falls back, the local chains win — and nothing leaks (the
     engine drains, a follow-up cold start still completes)."""
-    _, warm, _, ref = donor
-    srv_b, x = _mk_server(tmp_path)
+    srv_a, warm, _, ref = donor
+    srv_b, x = _mk_server(tmp_path, donor=srv_a)
     eng = srv_b.engines["mnet"]
     eng.fault_injector = FaultInjector(
         seed=3, rates={"warmstate.fetch": 1.0}, max_faults_per_key=None)
@@ -183,8 +198,7 @@ def test_injected_fetch_fault_falls_back_no_leaks(donor, tmp_path):
     np.testing.assert_array_equal(out, ref)
     events = ticket.job.job.fault_events
     assert any(e.get("action") == "fetch_fallback" for e in events)
-    if srv_b.io_engine is not None:
-        assert srv_b.io_engine.drain(10.0), "reads leaked after the race"
+    assert srv_b.io_engine.drain(10.0), "reads leaked after the race"
     # the pool survived the race + fallback: serve again, bit-identical
     out2 = np.asarray(
         srv_b.cold_start("mnet", x, peers=_peers(warm)).result().output)
